@@ -1,72 +1,11 @@
-"""Version compatibility shims for the JAX mesh-context API.
-
-Newer JAX exposes ``jax.sharding.get_abstract_mesh`` /
-``jax.sharding.set_mesh``; on 0.4.x the equivalent is the thread-local
-*physical* mesh entered via ``with mesh:``. These helpers paper over the
-difference so sharding hints degrade identically on both: off-mesh they
-return ``None`` and callers no-op.
-"""
+"""The mesh active for the current trace, as sharding hints need it."""
 from __future__ import annotations
-
-import contextlib
 
 import jax
 
 
 def current_mesh():
-    """The mesh active for the current trace, or ``None`` when off-mesh.
-
-    Prefers the abstract mesh (JAX >= 0.5); falls back to the physical
-    mesh thread resource that ``with mesh:`` installs on 0.4.x.
-    """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        m = get_abstract()
-        if m is not None and not m.empty:
-            return m
-    try:
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        return None
-    if m is None or m.empty:
-        return None
-    return m
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a bound mesh axis inside shard_map/pmap.
-
-    ``jax.lax.axis_size`` on newer JAX; on 0.4.x ``jax.core.axis_frame``
-    resolves the name against the ambient axis env (returning either the
-    size directly or a frame carrying it, depending on minor version).
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-
-    def one(name) -> int:
-        frame = jax.core.axis_frame(name)
-        return frame if isinstance(frame, int) else frame.size
-
-    if isinstance(axis_name, (tuple, list)):
-        n = 1
-        for name in axis_name:
-            n *= one(name)
-        return n
-    return one(axis_name)
-
-
-def mesh_context(mesh):
-    """Context manager activating ``mesh`` for tracing/compilation.
-
-    ``jax.sharding.set_mesh`` where available, else the 0.4.x
-    ``with mesh:`` physical-mesh context (a Mesh is its own context
-    manager there).
-    """
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    if mesh is None:
-        return contextlib.nullcontext()
-    return mesh
+    """The abstract mesh of the current trace, or ``None`` off-mesh
+    (callers then skip their sharding hints)."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or m.empty else m

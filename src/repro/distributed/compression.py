@@ -25,8 +25,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import compat
-
 
 @dataclasses.dataclass(frozen=True)
 class CompressionConfig:
@@ -56,7 +54,7 @@ def ring_allreduce_int8(x: jax.Array, axis_name: str) -> jax.Array:
     every hop re-quantized to int8 (+1 f32 scale per chunk). Must be
     called inside shard_map/pmap with ``axis_name`` bound.
     """
-    P = compat.axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     n = x.size
     pad = (-n) % P

@@ -21,8 +21,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import compat
-
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
@@ -78,7 +76,7 @@ def exchange_features(local_feats: jax.Array, ids: jax.Array, axis_name: str,
     ``request_layout`` for the two ownership conventions).
     Returns (feats (T, F), overflow bool[]).
     """
-    P = compat.axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     T = ids.shape[0]
     V_local, F = local_feats.shape
     req_ids, req_pos, overflow = request_layout(ids, P, per_peer_cap, V_local,

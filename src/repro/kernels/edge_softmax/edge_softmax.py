@@ -59,7 +59,8 @@ def _stats_kernel(heads, row_block_ref, first_ref, dst_ref, logit_ref,
     # exact per-row segment max of this chunk, real heads only:
     # (BE, H, BS) masked reduce over the edge axis. Padding edges have
     # an all-zero P row and padded heads never enter (sliced off).
-    lg3 = jnp.where(P[:, None, :] > 0, logit[:, :heads, None], NEG)
+    lg3 = jnp.where(jnp.expand_dims(P, 1) > 0,
+                    jnp.expand_dims(logit[:, :heads], 2), NEG)
     cmax = jnp.transpose(jnp.max(lg3, axis=0))         # (BS, H)
     if hp > heads:
         cmax = jnp.concatenate(

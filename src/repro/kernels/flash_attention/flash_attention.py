@@ -45,8 +45,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, window,
 
     def body(kb, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.ds(kb * bk, bk), slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.ds(kb * bk, bk), slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(kb * bk, bk), :].astype(jnp.float32)
+        v = v_ref[pl.ds(kb * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))   # (BQ, BK)
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
@@ -98,13 +98,15 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, softcap=None,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, None, bq, hd), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((None, None, Sk, hd),
+            pl.BlockSpec((pl.squeezed, pl.squeezed, bq, hd),
+                         lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((pl.squeezed, pl.squeezed, Sk, hd),
                          lambda b, h, i, hkv=Hkv, hq=Hq: (b, h * hkv // hq, 0, 0)),
-            pl.BlockSpec((None, None, Sk, hd),
+            pl.BlockSpec((pl.squeezed, pl.squeezed, Sk, hd),
                          lambda b, h, i, hkv=Hkv, hq=Hq: (b, h * hkv // hq, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, None, bq, hd), lambda b, h, i: (b, h, i, 0)),
+        out_specs=pl.BlockSpec((pl.squeezed, pl.squeezed, bq, hd),
+                               lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, hd), q.dtype),
         interpret=interpret,
     )(qt, kt, vt)

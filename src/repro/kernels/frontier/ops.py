@@ -6,8 +6,9 @@ block itself, not the graph), and post-processes with cheap cap-sized
 XLA ops (the ascending sort of the deduped output, mask/overflow
 assembly). Semantics are bit-compatible with kernels/frontier/ref.py —
 see that module's contract notes (on a hash-table give-up only the
-overflow flag is contractual). These wrappers are what the ``"pallas"``
-graph-ops backend registers.
+overflow flag is contractual). The ``"pallas"`` graph-ops backend
+dispatches here only with ``impl="serial"`` and only in interpret mode:
+the TPU compiler refuses the scalar VMEM stores of these kernels.
 """
 from __future__ import annotations
 

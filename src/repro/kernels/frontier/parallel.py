@@ -1,48 +1,58 @@
-"""Grid-parallel tiled Pallas kernels for the frontier primitives.
+"""Grid-parallel Pallas kernels for the frontier primitives.
 
-The serial kernels (kernels/frontier/frontier.py) are single-grid-step
-scalar scans — one ``fori_loop`` iteration per element. These kernels
-replace the element-at-a-time loops with lane-parallel work over tiles:
+Every primitive of the family reduces to ONE Pallas building block, a
+lane-dense bitonic sort of int32 word tuples (:func:`sort_words`), plus
+cap-sized XLA elementwise ops, scans and gathers around it (TPU
+gathers are fine; the data motion that needs a kernel is the sort):
 
-  * ``hash_dedup``   — a grid over value tiles builds per-tile stripes
-                       (tile-local bitonic sort → first-of-run dedup →
-                       seed filter by vectorized binary search), then a
-                       cooperative merge pass sorts the stripe buffer,
-                       counts distinct survivors, and compacts them to
-                       the ascending ``new`` contract; the value→slot
-                       lookup is a batched binary search over the
-                       sorted ``[seeds ; new]`` table.
-  * ``compact``      — block-parallel prefix-scan compaction: each grid
-                       step sorts one tile's flag positions, reads the
-                       running cross-tile offset (the scan carry, in
-                       SMEM), and stores its compacted run contiguously.
-  * ``compact_perm`` — one tiled bitonic sort; when the key range fits,
-                       (key, index) packs into a single int32 word
-                       (stability for free — packed words are unique),
-                       else a two-word lexicographic compare-exchange.
-  * ``segment_select`` — a tiled (slot, key-bits) sort extracts every
-                       segment's take-th-smallest threshold in one
-                       pass, replacing the 31-pass serial bisection;
-                       inclusion then replays the reference's
-                       threshold/tie-rank formula in arrival order.
-  * ``masked_cdf_draw`` — all draws binary-search the VMEM CDF in
-                       lockstep (log2(C) vectorized steps), instead of
-                       one ``while_loop`` per draw.
+  * ``hash_dedup``     — sort ``[seeds ; values]`` by (value, position):
+                         runs of equal values are adjacent, a seed sorts
+                         first in its run, so run heads give the unique
+                         new values and every run's slot in
+                         ``[seeds ; new]``; a second sort keyed by
+                         position puts the slots back in edge order.
+  * ``compact``        — sort flag-tagged positions (set flags first, in
+                         arrival order) and keep the head.
+  * ``compact_perm``   — sort (key, index) — packed into one word when
+                         the key range allows — and keep the indices.
+  * ``segment_select`` — sort (segment, key bits): each segment's
+                         take-th smallest key sits at a known position;
+                         inclusion then replays the reference's
+                         threshold / tie-rank formula in arrival order.
+  * ``masked_cdf_draw`` — sort draws together with the CDF: a draw's
+                         rank among the CDF entries is its inverse-CDF
+                         index; a sort keyed by position restores draw
+                         order.
+
+The sort works on a ``(N // lanes, lanes)`` view of each word (lanes =
+128 on TPU), in blocks of ``tile`` elements that live in VMEM:
+
+  1. one kernel sorts every block (all bitonic stages whose
+     compare-exchange distance is below ``tile``), alternating
+     direction by block as the bitonic network requires;
+  2. each later stage runs its compare-exchange steps at distances
+     >= ``tile`` as grid passes over HBM — grid step ``b`` reads its
+     own block and partner block ``b ^ (d // tile)`` and keeps the min
+     or the max — then finishes the distances below ``tile`` inside
+     each block.
+
+Inside a block, a compare-exchange at distance ``d`` fetches partners
+with two ``pltpu.roll`` rotations (lanes when ``d < lanes``, sublanes
+otherwise) and picks the right one by rotating the index vector the
+same way, so the kernel does not depend on the rotation's direction
+convention. The pass kernels take the stage and distance as scalar
+prefetch operands and run under ``lax.fori_loop``, so each sort
+compiles three kernels whatever its length.
 
 Bit-compatibility: identical to kernels/frontier/ref.py on every
-contractual output (see ref.py's notes) whenever no stripe overflows —
-and the default ``stripe_cap == tile`` makes stripe overflow
-impossible, since a tile holds at most ``tile`` distinct values.
-Forcing ``stripe_cap < tile`` (tests, and the doubled-caps drill)
-exercises the cross-tile overflow propagation: any tile with more
-survivors than its stripe raises the same give-up flag the serial
-hash-table path raises, healed by the doubled-caps replay.
+contractual output (see ref.py's notes). The sort never drops or
+duplicates a word tuple, including equal keys: at equal keys both
+sides of a compare-exchange keep their own element.
 
-Tile sizes are the knobs the autotune cache (repro/ops/autotune.py)
-tunes; every wrapper takes them as static arguments with deterministic
-defaults. Sort/search widths are padded to powers of two — padding is
-cap-derived, so the no-V-sized-buffer property of the family is
-preserved (and re-checked by the jaxpr-walk gate).
+``tile`` is the knob the autotune cache (repro/ops/autotune.py) tunes.
+Compiled kernels need ``tile >= 1024`` (one (8, 128) int32 vreg tile
+per block); interpret mode accepts any power of two, which is how the
+tests drive multi-block passes on small inputs.
 """
 from __future__ import annotations
 
@@ -56,9 +66,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.frontier.ref import DedupResult, normalized_cdf
 
-_INT_MAX = jnp.int32(2**31 - 1)
+_INT_MAX = 2**31 - 1
 
-DEFAULT_TILE = 512
+DEFAULT_TILE = 8192
+LANES = 128
+_MIN_COMPILED_TILE = 8 * LANES
 _MIN_TILE = 8  # keeps padded dims off the jaxpr gate's prime V window
 
 
@@ -69,465 +81,340 @@ def _pow2_at_least(x: int) -> int:
     return p
 
 
-def _col(x):
-    return jnp.reshape(x, (-1, 1))
-
-
-def _i32(shape):
-    return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-
 def _iota(n: int):
-    return jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
+    return jnp.arange(n, dtype=jnp.int32)
 
 
 # ---------------------------------------------------------------------------
-# in-kernel building blocks: bitonic compare-exchange networks + scans
+# the sort: in-block bitonic stages + HBM grid passes
 # ---------------------------------------------------------------------------
 
-def _cmp_exchange(keys, pays, d: int, desc):
-    """One bitonic step at distance ``d``: lexicographic over the
-    ``keys`` words, ``pays`` carried through the swaps. Arrays are
-    (..., N); ``desc`` is the per-block direction, (N // 2d, 1)."""
-    shp = keys[0].shape
-    n = shp[-1]
-    resh = lambda x: x.reshape(shp[:-1] + (n // (2 * d), 2, d))
-    a_k = [resh(k)[..., 0, :] for k in keys]
-    b_k = [resh(k)[..., 1, :] for k in keys]
-    a_p = [resh(p)[..., 0, :] for p in pays]
-    b_p = [resh(p)[..., 1, :] for p in pays]
-    gt = a_k[0] > b_k[0]
-    eq = a_k[0] == b_k[0]
-    for i in range(1, len(keys)):
-        gt |= eq & (a_k[i] > b_k[i])
-        eq &= a_k[i] == b_k[i]
-    swap = gt != desc
-
-    def merge(a, b):
-        na = jnp.where(swap, b, a)
-        nb = jnp.where(swap, a, b)
-        return jnp.stack([na, nb], axis=-2).reshape(shp)
-
-    return ([merge(a, b) for a, b in zip(a_k, b_k)],
-            [merge(a, b) for a, b in zip(a_p, b_p)])
+def _exchange(words, partner, take_min, n_keys: int):
+    """Keep, per element, the lexicographic min (``take_min``) or max
+    of itself and its partner over the first ``n_keys`` words; the
+    remaining words ride along. Equal keys keep their own element."""
+    p_lt = partner[0] < words[0]
+    eq = partner[0] == words[0]
+    for i in range(1, n_keys):
+        p_lt = p_lt | (eq & (partner[i] < words[i]))
+        eq = eq & (partner[i] == words[i])
+    use_p = (take_min & p_lt) | (~take_min & ~(p_lt | eq))
+    return [jnp.where(use_p, p, w) for w, p in zip(words, partner)]
 
 
-def _bitonic_sort(keys: Sequence, pays: Sequence = ()) -> Tuple[list, list]:
-    """Ascending bitonic sort over the last axis (a static power of
-    two). ``keys`` are compared lexicographically; ``pays`` ride along.
-    log^2(N) fully vectorized compare-exchange steps — every lane works
-    every step, unlike the serial kernels' one-element loops."""
-    keys, pays = list(keys), list(pays)
-    n = keys[0].shape[-1]
-    for st in range(n.bit_length() - 1):
+def _block_step(words, local, d: int, lanes: int, rows: int, desc,
+                n_keys: int):
+    """Compare-exchange at in-block distance ``d`` (a static power of
+    two below the block size); ``desc`` marks descending elements."""
+    if d < lanes:
+        axis, s, size = 1, d, lanes
+    else:
+        axis, s, size = 0, d // lanes, rows
+    from_a = pltpu.roll(local, s, axis) == (local ^ d)
+    partner = [jnp.where(from_a, pltpu.roll(w, s, axis),
+                         pltpu.roll(w, size - s, axis)) for w in words]
+    take_min = ((local & d) == 0) != desc
+    return _exchange(words, partner, take_min, n_keys)
+
+
+def _local_index(rows: int, lanes: int):
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+    return r * lanes + c
+
+
+def _sort_blocks_kernel(*refs, n_words: int, n_keys: int):
+    """Bitonic stages 0..log2(tile)-1 of the global network, in one
+    block: afterwards each block is sorted, ascending or descending by
+    the direction bit of its global index."""
+    ins, outs = refs[:n_words], refs[n_words:]
+    rows, lanes = ins[0].shape
+    tile = rows * lanes
+    local = _local_index(rows, lanes)
+    gidx = pl.program_id(0) * tile + local
+    words = [r[...] for r in ins]
+    for st in range(tile.bit_length() - 1):
+        desc = ((gidx >> (st + 1)) & 1) != 0
         for sub in range(st, -1, -1):
-            d = 1 << sub
-            m = _iota(n // (2 * d))
-            desc = ((((m * (2 * d)) >> (st + 1)) & 1) != 0)[:, None]
-            keys, pays = _cmp_exchange(keys, pays, d, desc)
-    return keys, pays
+            words = _block_step(words, local, 1 << sub, lanes, rows, desc,
+                                n_keys)
+    for r, w in zip(outs, words):
+        r[...] = w
 
 
-def _prefix_incl(x):
-    """Inclusive prefix sum by Hillis-Steele doubling shifts: log2(N)
-    vectorized add steps (the block-parallel scan the compaction and
-    tie-ranking passes share)."""
-    n = x.shape[0]
-    d = 1
-    while d < n:
-        x = x + jnp.concatenate([jnp.zeros((d,), x.dtype), x[:-d]])
-        d *= 2
-    return x
+def _merge_blocks_kernel(st_ref, *refs, n_words: int, n_keys: int):
+    """The in-block tail of stage ``st`` (distances below the block)."""
+    ins, outs = refs[:n_words], refs[n_words:]
+    rows, lanes = ins[0].shape
+    tile = rows * lanes
+    local = _local_index(rows, lanes)
+    desc = (((pl.program_id(0) * tile + local) >> (st_ref[0] + 1)) & 1) != 0
+    words = [r[...] for r in ins]
+    for sub in range(tile.bit_length() - 2, -1, -1):
+        words = _block_step(words, local, 1 << sub, lanes, rows, desc,
+                            n_keys)
+    for r, w in zip(outs, words):
+        r[...] = w
 
 
-def _searchsorted(tbl, q, hi_cap: int):
-    """Vectorized left binary search of every ``q`` in sorted ``tbl``
-    (all queries advance in lockstep — log2 steps of gathers)."""
-    lo = jnp.zeros(q.shape, jnp.int32)
-    hi = jnp.full(q.shape, hi_cap, jnp.int32)
-    for _ in range(max(hi_cap.bit_length(), 1)):
-        mid = (lo + hi) >> 1
-        ge = tbl[jnp.clip(mid, 0, hi_cap - 1)] >= q
-        lo = jnp.where(ge, lo, mid + 1)
-        hi = jnp.where(ge, mid, hi)
-    return lo
+def _cross_blocks_kernel(st_ref, db_ref, *refs, n_words: int, n_keys: int):
+    """One compare-exchange step of stage ``st`` at distance
+    ``db_ref[0]`` blocks: this block against its partner block."""
+    own, partner = refs[:2 * n_words:2], refs[1:2 * n_words:2]
+    outs = refs[2 * n_words:]
+    rows, lanes = own[0].shape
+    b = pl.program_id(0)
+    # take the min iff this block is the low one of an ascending pair
+    # or the high one of a descending pair (scalar, as an int vector:
+    # Mosaic cannot broadcast a scalar bool)
+    low = jnp.where((b & db_ref[0]) == 0, 1, 0)
+    desc = ((b * (rows * lanes)) >> (st_ref[0] + 1)) & 1
+    take_min = jnp.full((rows, lanes), low ^ desc, jnp.int32) != 0
+    words = _exchange([r[...] for r in own], [r[...] for r in partner],
+                      take_min, n_keys)
+    for r, w in zip(outs, words):
+        r[...] = w
+
+
+def _block_shape(n: int, tile: int, interpret: bool) -> Tuple[int, int]:
+    if not interpret:
+        tile = max(tile, _MIN_COMPILED_TILE)
+    tile = min(_pow2_at_least(tile), n)
+    lanes = min(LANES, tile)
+    return tile // lanes, lanes
+
+
+def sort_words(words: Sequence[jax.Array], n_keys: int,
+               tile: int = DEFAULT_TILE,
+               interpret: bool = False) -> list:
+    """Sort int32 word tuples ascending, lexicographically over the
+    first ``n_keys`` words; the remaining words are carried along.
+    Every word has the same power-of-two length (>= 8)."""
+    n = words[0].shape[0]
+    if n & (n - 1) or n < _MIN_TILE:
+        raise ValueError(f"sort_words needs a power-of-two length >= "
+                         f"{_MIN_TILE}, got {n}")
+    rows, lanes = _block_shape(n, tile, interpret)
+    tile = rows * lanes
+    nw = len(words)
+    nb = n // tile
+    x = [w.astype(jnp.int32).reshape(n // lanes, lanes) for w in words]
+    shape = [jax.ShapeDtypeStruct(x[0].shape, jnp.int32)] * nw
+    own = pl.BlockSpec((rows, lanes), lambda b, *_: (b, 0))
+    x = pl.pallas_call(
+        functools.partial(_sort_blocks_kernel, n_words=nw, n_keys=n_keys),
+        grid=(nb,), in_specs=[own] * nw, out_specs=[own] * nw,
+        out_shape=shape, interpret=interpret,
+    )(*x)
+    log_t, log_n = tile.bit_length() - 1, n.bit_length() - 1
+    if log_n == log_t:
+        return [w.reshape(n) for w in x]
+
+    partner = pl.BlockSpec((rows, lanes),
+                           lambda b, st, db: (jnp.bitwise_xor(b, db[0]), 0))
+    cross = pl.pallas_call(
+        functools.partial(_cross_blocks_kernel, n_words=nw, n_keys=n_keys),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(nb,),
+            in_specs=[own, partner] * nw, out_specs=[own] * nw),
+        out_shape=shape, interpret=interpret)
+    merge = pl.pallas_call(
+        functools.partial(_merge_blocks_kernel, n_words=nw, n_keys=n_keys),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(nb,),
+            in_specs=[own] * nw, out_specs=[own] * nw),
+        out_shape=shape, interpret=interpret)
+
+    def stage(st, x):
+        st_arr = jnp.reshape(st, (1,)).astype(jnp.int32)
+
+        def step(j, x):
+            db = jnp.left_shift(jnp.int32(1), st - j - log_t)
+            pairs = [a for w in x for a in (w, w)]
+            return list(cross(st_arr, jnp.reshape(db, (1,)), *pairs))
+
+        x = jax.lax.fori_loop(0, st - log_t + 1, step, x)
+        return list(merge(st_arr, *x))
+
+    x = jax.lax.fori_loop(log_t, log_n, stage, list(x))
+    return [w.reshape(n) for w in x]
+
+
+def _pad(x, n: int, fill):
+    return jnp.pad(x.astype(jnp.int32), (0, n - x.shape[0]),
+                   constant_values=fill)
+
+
+def _head(x, m: int, fill):
+    """``x[:m]``, padded with ``fill`` when ``x`` is shorter."""
+    return x[:m] if x.shape[0] >= m else _pad(x, m, fill)
+
+
+def _exclusive_cumsum(b):
+    b = b.astype(jnp.int32)
+    return jnp.cumsum(b) - b
 
 
 # ---------------------------------------------------------------------------
-# hash_dedup — tile stripes (grid) -> cooperative merge -> batched lookup
+# hash_dedup
 # ---------------------------------------------------------------------------
 
-def dedup_tiles_kernel(values_ref, mask_ref, sseeds_ref, stripes_ref,
-                       ovf_ref, *, stripe: int):
-    """Grid step t: dedup tile t into its stripe. Tile-local bitonic
-    sort makes duplicates adjacent; survivors (first-of-run, not a
-    seed) compact to the stripe head via a second payload-carrying
-    sort. A tile with more survivors than ``stripe`` raises the shared
-    overflow flag — the cross-tile analogue of the serial hash table's
-    give-up."""
-    t = pl.program_id(0)
-    bt = values_ref.shape[0]
-    sp = sseeds_ref.shape[0]
-
-    @pl.when(t == 0)
-    def _():
-        ovf_ref[0, 0] = jnp.int32(0)
-
-    imax = jnp.int32(2**31 - 1)
-    v = values_ref[:, 0]
-    valid = (mask_ref[:, 0] != 0) & (v >= 0)
-    (vs,), _ = _bitonic_sort((jnp.where(valid, v, imax),))
-    present = vs != imax
-    uniq = present & jnp.concatenate(
-        [jnp.ones((1,), jnp.bool_), vs[1:] != vs[:-1]])
-    seeds = sseeds_ref[:, 0]
-    j = jnp.clip(_searchsorted(seeds, vs, sp), 0, sp - 1)
-    keep = uniq & (seeds[j] != vs)
-    cnt = jnp.sum(keep.astype(jnp.int32))
-    (_, ), (pv,) = _bitonic_sort(
-        (jnp.where(keep, _iota(bt), bt + _iota(bt)),), (vs,))
-    stripes_ref[...] = jnp.where(_iota(stripe) < cnt, pv[:stripe],
-                                 imax)[:, None]
-
-    @pl.when(cnt > stripe)
-    def _():
-        ovf_ref[0, 0] = jnp.int32(1)
-
-
-def dedup_merge_kernel(stripes_ref, new_ref, num_ref):
-    """Cooperative merge: one sort makes cross-tile duplicates
-    adjacent, the distinct survivors are counted exactly, and a second
-    sort compacts them — already ascending, the ``new`` contract, with
-    no insertion-order fixup needed."""
-    m = new_ref.shape[0]
-    imax = jnp.int32(2**31 - 1)
-    (s,), _ = _bitonic_sort((stripes_ref[:, 0],))
-    uniq = (s != imax) & jnp.concatenate(
-        [jnp.ones((1,), jnp.bool_), s[1:] != s[:-1]])
-    num_ref[0, 0] = jnp.sum(uniq.astype(jnp.int32))
-    (s3,), _ = _bitonic_sort((jnp.where(uniq, s, imax),))
-    head = s3[:m]
-    new_ref[...] = jnp.where((_iota(m) < num_ref[0, 0]) & (head != imax),
-                             head, -1)[:, None]
-
-
-def lookup_batched_kernel(tvs_ref, slots_tbl_ref, values_ref, mask_ref,
-                          out_ref):
-    """Batched value→slot lookup: every edge binary-searches the sorted
-    ``[seeds ; new]`` table in lockstep (replacing one linear-probe
-    ``while_loop`` per edge)."""
-    kp = tvs_ref.shape[0]
-    tvs = tvs_ref[:, 0]
-    v = values_ref[:, 0]
-    valid = (mask_ref[:, 0] != 0) & (v >= 0)
-    j = jnp.clip(_searchsorted(tvs, v, kp), 0, kp - 1)
-    found = valid & (tvs[j] == v)
-    out_ref[...] = jnp.where(found, slots_tbl_ref[:, 0][j], -1)[:, None]
-
-
-@functools.partial(jax.jit, static_argnames=("new_cap", "tile", "stripe_cap",
-                                             "interpret"))
-def _dedup_parallel(values, mask, seeds_in, new_cap: int, tile: int,
-                    stripe_cap: int, interpret: bool):
-    e = values.shape[0]
-    ep = ((e + tile - 1) // tile) * tile
-    t = ep // tile
-    vp = jnp.pad(values.astype(jnp.int32), (0, ep - e), constant_values=-1)
-    mp = jnp.pad(mask.astype(jnp.int32), (0, ep - e))
-    s = seeds_in.shape[0]
-    sp = _pow2_at_least(s)
-    sseeds = jnp.sort(jnp.pad(
-        jnp.where(seeds_in >= 0, seeds_in, _INT_MAX), (0, sp - s),
-        constant_values=_INT_MAX.item()))
-    cp = _pow2_at_least(t * stripe_cap)
-    stripes, ovf = pl.pallas_call(
-        functools.partial(dedup_tiles_kernel, stripe=stripe_cap),
-        grid=(t,),
-        in_specs=[pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-                  pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-                  pl.BlockSpec((sp, 1), lambda i: (0, 0))],
-        out_specs=(pl.BlockSpec((stripe_cap, 1), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))),
-        out_shape=(_i32((t * stripe_cap, 1)), _i32((1, 1))),
-        interpret=interpret,
-    )(_col(vp), _col(mp), _col(sseeds))
-    spad = jnp.pad(stripes[:, 0], (0, cp - t * stripe_cap),
-                   constant_values=_INT_MAX.item())
-    m = min(new_cap, cp)
-    new_raw, num = pl.pallas_call(
-        dedup_merge_kernel,
-        out_shape=(_i32((m, 1)), _i32((1, 1))),
-        interpret=interpret,
-    )(_col(spad))
-    new = jnp.pad(new_raw[:, 0], (0, new_cap - m), constant_values=-1)
-    return new, num[0, 0], ovf[0, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _lookup_parallel(next_vals, values, mask, interpret: bool):
-    k = next_vals.shape[0]
-    kp = _pow2_at_least(k)
-    tbl = jnp.pad(jnp.where(next_vals >= 0, next_vals, _INT_MAX),
-                  (0, kp - k), constant_values=_INT_MAX.item())
-    order = jnp.argsort(tbl).astype(jnp.int32)
-    slots_tbl = jnp.where(order < k, order, -1)
-    out = pl.pallas_call(
-        lookup_batched_kernel,
-        out_shape=_i32((values.shape[0], 1)),
-        interpret=interpret,
-    )(_col(tbl[order]), _col(slots_tbl), _col(values.astype(jnp.int32)),
-      _col(mask.astype(jnp.int32)))
-    return out[:, 0]
+@functools.partial(jax.jit, static_argnames=("new_cap", "tile", "interpret"))
+def _dedup(values, mask, seeds, new_cap: int, tile: int, interpret: bool):
+    e, s = values.shape[0], seeds.shape[0]
+    n = _pow2_at_least(s + e)
+    valid = mask & (values >= 0)
+    key = _pad(jnp.concatenate([jnp.where(seeds >= 0, seeds, _INT_MAX),
+                                jnp.where(valid, values, _INT_MAX)]),
+               n, _INT_MAX)
+    v, pos = sort_words([key, _iota(n)], 2, tile, interpret)
+    live = v != _INT_MAX
+    head = jnp.concatenate([jnp.ones((1,), bool), v[1:] != v[:-1]])
+    # a seed has the smallest position of its run, so it is the head
+    seed_at = pos < s
+    new_head = head & live & ~seed_at
+    num_new = jnp.sum(new_head.astype(jnp.int32))
+    rank = jnp.cumsum(new_head.astype(jnp.int32)) - 1
+    run = jax.lax.cummax(jnp.where(head, _iota(n), 0))
+    slot = jnp.where(seed_at[run], pos[run],
+                     jnp.where(rank[run] < new_cap, s + rank[run], -1))
+    slot = jnp.where(live, slot, -1)
+    (newv,) = sort_words([jnp.where(new_head, v, _INT_MAX)], 1, tile,
+                         interpret)
+    new = jnp.where(_iota(new_cap) < num_new, _head(newv, new_cap, -1), -1)
+    _, by_pos = sort_words([pos, slot], 1, tile, interpret)
+    return new, by_pos[s:s + e], num_new
 
 
 def hash_dedup_block_parallel(values: jax.Array, mask: jax.Array,
                               seeds: Optional[jax.Array], new_cap: int,
                               tile: int = DEFAULT_TILE,
-                              stripe_cap: Optional[int] = None,
                               interpret: bool = False) -> DedupResult:
-    """Grid-parallel hash_dedup: per-tile stripes + cooperative merge +
-    batched lookup. Bit-exact vs ref.hash_dedup (and the serial kernel)
-    whenever no stripe overflows — guaranteed at the default
-    ``stripe_cap == tile``. Smaller stripes trade merge width for a
-    possible flagged give-up, exactly like an undersized serial hash
-    table."""
-    e = values.shape[0]
-    tile = min(_pow2_at_least(tile), _pow2_at_least(e))
-    if stripe_cap is None:
-        stripe_cap = tile
-    stripe_cap = max(1, min(stripe_cap, tile))
-    seeds_in = (jnp.full((1,), -1, jnp.int32) if seeds is None
+    """Sort-based hash_dedup (contract of ref.hash_dedup, bit-exact)."""
+    seeds_in = (jnp.zeros((0,), jnp.int32) if seeds is None
                 else seeds.astype(jnp.int32))
-    new, num_new, stripe_ovf = _dedup_parallel(
-        values, mask, seeds_in, new_cap, tile, stripe_cap, interpret)
-    if seeds is not None:
-        next_vals = jnp.concatenate([seeds.astype(jnp.int32), new])
-    else:
-        next_vals = new
-    slots = _lookup_parallel(next_vals, values, mask, interpret)
-    overflow = (num_new > new_cap) | (stripe_ovf != 0)
+    new, slots, num_new = _dedup(values.astype(jnp.int32), mask, seeds_in,
+                                 new_cap, tile, interpret)
     return DedupResult(new=new, slots=slots, num_new=num_new,
-                       overflow=overflow)
+                       overflow=num_new > new_cap)
 
 
 # ---------------------------------------------------------------------------
-# compact — per-tile sorted positions + cross-tile scan carry (grid)
+# compact / compact_perm
 # ---------------------------------------------------------------------------
-
-def compact_tiles_kernel(flags_ref, sel_ref, num_ref, scratch_ref, off_ref):
-    """Grid step t: compact tile t's set flags and store the run at the
-    running offset (the prefix-scan carry over tile counts, in SMEM).
-    Within the tile a bitonic sort of flagged local positions replaces
-    the serial running-counter loop — order is preserved, so the
-    concatenated runs equal ``jnp.nonzero``'s output exactly."""
-    t = pl.program_id(0)
-    nt = pl.num_programs(0)
-    bt = flags_ref.shape[0]
-    cap = sel_ref.shape[0]
-
-    @pl.when(t == 0)
-    def _():
-        off_ref[0] = jnp.int32(0)
-        scratch_ref[...] = jnp.zeros(scratch_ref.shape, jnp.int32)
-
-    f = flags_ref[:, 0] != 0
-    cnt = jnp.sum(f.astype(jnp.int32))
-    (k,), _ = _bitonic_sort((jnp.where(f, _iota(bt), bt + _iota(bt)),))
-    run = jnp.where(_iota(bt) < cnt, k + t * bt, 0)
-    off = off_ref[0]
-
-    @pl.when(off < cap)
-    def _():
-        scratch_ref[pl.ds(off, bt), :] = run[:, None]
-
-    off_ref[0] = off + cnt
-
-    @pl.when(t == nt - 1)
-    def _():
-        num_ref[0, 0] = off + cnt
-        sel_ref[...] = scratch_ref[pl.ds(0, cap), :]
-
 
 @functools.partial(jax.jit, static_argnames=("cap", "tile", "interpret"))
 def compact_block_parallel(flags: jax.Array, cap: int,
                            tile: int = DEFAULT_TILE,
                            interpret: bool = False):
-    """Block-parallel stream compaction (contract of ref.compact)."""
+    """Order-preserving stream compaction (contract of ref.compact)."""
     e = flags.shape[0]
-    tile = min(_pow2_at_least(tile), _pow2_at_least(e))
-    ep = ((e + tile - 1) // tile) * tile
-    t = ep // tile
-    fp = jnp.pad(flags.astype(jnp.int32), (0, ep - e))
-    sel, num = pl.pallas_call(
-        compact_tiles_kernel,
-        grid=(t,),
-        in_specs=[pl.BlockSpec((tile, 1), lambda i: (i, 0))],
-        out_specs=(pl.BlockSpec((cap, 1), lambda i: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))),
-        out_shape=(_i32((cap, 1)), _i32((1, 1))),
-        scratch_shapes=[pltpu.VMEM((cap + tile, 1), jnp.int32),
-                        pltpu.SMEM((1,), jnp.int32)],
-        interpret=interpret,
-    )(_col(fp))
-    num = num[0, 0]
+    n = _pow2_at_least(e)
+    f = jnp.pad(flags.astype(bool), (0, n - e))
+    (k,) = sort_words([jnp.where(f, _iota(n), n + _iota(n))], 1, tile,
+                      interpret)
+    num = jnp.sum(flags.astype(jnp.int32))
+    sel = jnp.where(_iota(cap) < num, _head(k, cap, 0), 0)
     emask = jnp.arange(cap) < jnp.minimum(num, cap)
-    return sel[:, 0], emask, num
+    return sel, emask, num
 
 
-# ---------------------------------------------------------------------------
-# compact_perm — one tiled sort (packed single-word when the range fits)
-# ---------------------------------------------------------------------------
-
-def sort_packed_kernel(packed_ref, out_ref, *, idx_mask: int):
-    """Sort (key * N + index) packed words; unpacking the index is a
-    lane-wise AND (N is a power of two). Packed words are unique, so
-    the unstable bitonic network still yields the stable-by-key
-    permutation."""
-    (s,), _ = _bitonic_sort((packed_ref[:, 0],))
-    out_ref[...] = (s & idx_mask)[:, None]
-
-
-def sort_pairs_kernel(a_ref, b_ref, out_ref):
-    """Two-word lexicographic (key, index) sort for ranges too wide to
-    pack; the index word both carries the payload and breaks ties in
-    arrival order (stability)."""
-    _, (b,) = _bitonic_sort((a_ref[:, 0],), (b_ref[:, 0],))
-    out_ref[...] = b[:, None]
-
-
-@functools.partial(jax.jit, static_argnames=("num_keys", "interpret"))
+@functools.partial(jax.jit, static_argnames=("num_keys", "tile",
+                                             "interpret"))
 def compact_perm_block_parallel(keys: jax.Array, valid: jax.Array,
-                                num_keys: int,
+                                num_keys: int, tile: int = DEFAULT_TILE,
                                 interpret: bool = False) -> jax.Array:
-    """Stable ascending-key permutation (contract of ref.compact_perm)
-    by one tiled bitonic sort instead of the serial counting sort."""
+    """Stable ascending-key permutation (contract of ref.compact_perm).
+    (key, index) pairs are unique, so the sort's order is the stable
+    one; they pack into one word when the key range allows."""
     e = keys.shape[0]
-    ep = _pow2_at_least(e)
-    eff = jnp.where(valid, jnp.clip(keys, -1, num_keys - 1), num_keys) + 1
-    effp = jnp.pad(eff.astype(jnp.int32), (0, ep - e),
-                   constant_values=num_keys + 1)
-    idx = _iota(ep)
-    if (num_keys + 2) * ep < 2**31:
-        out = pl.pallas_call(
-            functools.partial(sort_packed_kernel, idx_mask=ep - 1),
-            out_shape=_i32((ep, 1)),
-            interpret=interpret,
-        )(_col(effp * ep + idx))
-    else:
-        # padded entries carry idx >= E, sorting after every real entry
-        # of the same key — the slice below drops exactly them
-        out = pl.pallas_call(
-            sort_pairs_kernel,
-            out_shape=_i32((ep, 1)),
-            interpret=interpret,
-        )(_col(effp), _col(idx))
-    return out[:e, 0]
+    n = _pow2_at_least(e)
+    eff = _pad(jnp.where(valid, jnp.clip(keys, -1, num_keys - 1),
+                         num_keys) + 1, n, num_keys + 1)
+    if (num_keys + 2) * n < 2**31:
+        (s,) = sort_words([eff * n + _iota(n)], 1, tile, interpret)
+        return (s & (n - 1))[:e]
+    # padded entries carry index >= E and sort after every real entry
+    _, idx = sort_words([eff, _iota(n)], 2, tile, interpret)
+    return idx[:e]
 
 
 # ---------------------------------------------------------------------------
-# segment_select — tiled (slot, key) sort -> thresholds -> rank filter
+# segment_select
 # ---------------------------------------------------------------------------
 
-def select_sort_kernel(keys_ref, slot_ref, segstart_ref, take_ref, inc_ref,
-                       *, e_real: int):
-    """One tiled two-word sort ranks every edge within its segment;
-    each segment's take-th-smallest key pops out by position (segments
-    stay contiguous under the (slot, key) order), replacing the serial
-    bisection's 31 masked counting passes. Inclusion then follows the
-    reference's threshold / tie-budget formula in arrival order —
-    bit-identical ties."""
-    ep = keys_ref.shape[0]
-    s = segstart_ref.shape[0]
-    u = jax.lax.bitcast_convert_type(keys_ref[:, 0], jnp.int32)
-    slot = slot_ref[:, 0]
-    maskv = slot >= 0
+@functools.partial(jax.jit, static_argnames=("num_seeds", "tile",
+                                             "interpret"))
+def segment_select_block_parallel(keys: jax.Array, slot: jax.Array,
+                                  mask: jax.Array, seg_start: jax.Array,
+                                  take: jax.Array, num_seeds: int,
+                                  tile: int = DEFAULT_TILE,
+                                  interpret: bool = False) -> jax.Array:
+    """Per-segment smallest-``take`` selection (ref.segment_select
+    contract): one (segment, key) sort yields every segment's
+    take-th-smallest key by position (segments stay contiguous and
+    masked entries sit on the tail), then the reference's threshold /
+    tie-budget formula runs in arrival order — bit-identical ties."""
+    e = keys.shape[0]
+    n = _pow2_at_least(e)
+    s = num_seeds
+    u = jax.lax.bitcast_convert_type(keys.astype(jnp.float32), jnp.int32)
+    maskv = mask & (slot >= 0)
     sl = jnp.where(maskv, slot, s)
-    _, (us,) = _bitonic_sort((sl, u), (u,))
+    _, us = sort_words([_pad(sl, n, s), _pad(u, n, 0)], 2, tile, interpret)
 
     nv = jnp.sum(maskv.astype(jnp.int32))
-    starts = jnp.clip(segstart_ref[:, 0], 0, e_real)
-    ends = jnp.concatenate([starts[1:], jnp.full((1,), e_real, jnp.int32)])
+    starts = jnp.clip(seg_start, 0, e).astype(jnp.int32)
+    ends = jnp.concatenate([starts[1:], jnp.full((1,), e, jnp.int32)])
     present = jnp.clip(jnp.minimum(ends, nv) - starts, 0, None)
-    take = take_ref[:, 0]
     # the take-th smallest key of segment s sits at its sorted start +
     # take - 1; a segment whose buffer holds fewer than take edges
     # (expand truncation, already overflow-flagged) saturates the
     # threshold and includes everything present — same as the bisection
-    at = jnp.clip(jnp.minimum(starts, nv) + take - 1, 0, ep - 1)
+    at = jnp.clip(jnp.minimum(starts, nv) + take - 1, 0, n - 1)
     thresh = jnp.where(take == 0, 0,
-                       jnp.where(take <= present, us[at],
-                                 jnp.int32(2**31 - 1)))
-
+                       jnp.where(take <= present, us[at], _INT_MAX))
     cslot = jnp.clip(slot, 0, s - 1)
     te = thresh[cslot]
     lt = maskv & (u < te)
     ex = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                          _prefix_incl(lt.astype(jnp.int32))])
+                          jnp.cumsum(lt.astype(jnp.int32))])
     cnt_lt = ex[ends] - ex[starts]
     eq = maskv & (u == te)
-    excl = _prefix_incl(eq.astype(jnp.int32)) - eq.astype(jnp.int32)
-    base = excl[jnp.clip(segstart_ref[:, 0], 0, ep - 1)]
+    excl = _exclusive_cumsum(eq)
+    base = excl[jnp.clip(seg_start, 0, e - 1)]
     eq_rank = excl - base[cslot]
     budget = (take - cnt_lt)[cslot]
-    inc = lt | (eq & (eq_rank < budget))
-    inc_ref[...] = inc.astype(jnp.int32)[:, None]
-
-
-@functools.partial(jax.jit, static_argnames=("num_seeds", "interpret"))
-def segment_select_block_parallel(keys: jax.Array, slot: jax.Array,
-                                  mask: jax.Array, seg_start: jax.Array,
-                                  take: jax.Array, num_seeds: int,
-                                  interpret: bool = False) -> jax.Array:
-    """Per-segment smallest-``take`` selection (ref.segment_select
-    contract) via one tiled sort. Unlike the serial insertion-buffer
-    kernel this needs ``seg_start`` (like the XLA reference) and has no
-    static fanout bound."""
-    e = keys.shape[0]
-    ep = _pow2_at_least(e)
-    slot_in = jnp.where(mask, slot, -1).astype(jnp.int32)
-    kp = jnp.pad(keys.astype(jnp.float32), (0, ep - e))
-    sp = jnp.pad(slot_in, (0, ep - e), constant_values=-1)
-    inc = pl.pallas_call(
-        functools.partial(select_sort_kernel, e_real=e),
-        out_shape=_i32((ep, 1)),
-        interpret=interpret,
-    )(_col(kp), _col(sp), _col(seg_start.astype(jnp.int32)),
-      _col(take.astype(jnp.int32)))
-    return inc[:e, 0] != 0
+    return lt | (eq & (eq_rank < budget))
 
 
 # ---------------------------------------------------------------------------
-# masked_cdf_draw — lockstep batched binary search
+# masked_cdf_draw
 # ---------------------------------------------------------------------------
 
-def batched_search_kernel(cdf_ref, u_ref, out_ref):
-    """All draws advance one bisection level per step over the
-    VMEM-resident CDF — log2(C) vectorized steps total, versus one
-    serial ``while_loop`` per draw."""
-    c = cdf_ref.shape[0]
-    cdf = cdf_ref[:, 0]
-    u = u_ref[:, 0]
-    lo = jnp.zeros(u.shape, jnp.int32)
-    hi = jnp.full(u.shape, c, jnp.int32)
-    for _ in range(max(c.bit_length(), 1)):
-        mid = (lo + hi) >> 1
-        ge = cdf[jnp.clip(mid, 0, c - 1)] >= u
-        lo = jnp.where(ge, lo, mid + 1)
-        hi = jnp.where(ge, mid, hi)
-    out_ref[...] = jnp.clip(lo, 0, c - 1)[:, None]
+def _float_key(x):
+    """int32 view ordering like float32 (-0.0 folded onto +0.0)."""
+    b = jax.lax.bitcast_convert_type(
+        jnp.where(x == 0, 0.0, x).astype(jnp.float32), jnp.int32)
+    return b ^ ((b >> 31) & _INT_MAX)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def masked_cdf_draw_block_parallel(p: jax.Array, valid: jax.Array,
-                                   u: jax.Array,
+                                   u: jax.Array, tile: int = DEFAULT_TILE,
                                    interpret: bool = False) -> jax.Array:
     """Inverse-CDF draws (ref.masked_cdf_draw contract); the CDF comes
     from the shared ``normalized_cdf`` so draws cannot drift across
-    backends."""
+    backends. A draw sorts before CDF entries equal to it, so the CDF
+    entries ahead of it are those below it: its left search index."""
     cdf = normalized_cdf(p, valid)
-    out = pl.pallas_call(
-        batched_search_kernel,
-        out_shape=_i32((u.shape[0], 1)),
-        interpret=interpret,
-    )(_col(cdf.astype(jnp.float32)), _col(u.astype(jnp.float32)))
-    return out[:, 0]
+    c, nd = cdf.shape[0], u.shape[0]
+    n = _pow2_at_least(c + nd)
+    key = _pad(jnp.concatenate([_float_key(u), _float_key(cdf)]), n,
+               _INT_MAX)
+    _, pos = sort_words([key, _iota(n)], 2, tile, interpret)
+    is_draw = pos < nd
+    below = _iota(n) - _exclusive_cumsum(is_draw)
+    _, draws = sort_words([pos, jnp.clip(below, 0, c - 1)], 1, tile,
+                          interpret)
+    return draws[:nd]

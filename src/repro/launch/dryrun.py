@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs as cfgreg
-from repro.distributed import compat
 from repro.configs.labor_gcn import GNNWorkloadConfig
 from repro.distributed import sharding as sh
 from repro.launch import roofline as rl
@@ -118,7 +117,7 @@ def lower_lm_cell(arch: str, shape_name: str, mesh, *, seq_shard_cache=True,
     param_specs = sh.shard_params_specs(
         lambda: stack.init_params(jax.random.key(0), cfg), mesh)
 
-    with compat.mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         if shape.kind == "train":
             opt_cfg = adam.AdamConfig(
                 lr=1e-3,
@@ -190,7 +189,7 @@ def lower_gnn_cell(arch: str, mesh):
         global_batch=meta["global_batch"], num_vertices=cfg.num_vertices,
         num_edges=int(cfg.num_vertices * cfg.avg_degree),
         feature_dim=cfg.feature_dim)
-    with compat.mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         args = (pspec, ospec, espec, ins["indptr"], ins["indices"],
                 ins["features"], ins["labels"], ins["seeds"], ins["key"])
         lowered = engine.step_fn.lower(*args)

@@ -2,11 +2,7 @@
 from __future__ import annotations
 
 import jax
-
-try:  # JAX >= 0.5: explicit/auto axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # 0.4.x: meshes have no axis types — GSPMD auto only
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -28,8 +24,6 @@ def make_mesh(shape, axes):
             f"need {n} devices for mesh {shape}, have {len(devs)} — the "
             "dry-run launcher must set XLA_FLAGS="
             "--xla_force_host_platform_device_count before importing jax")
-    if AxisType is None:
-        return jax.make_mesh(shape, axes, devices=devs[:n])
     return jax.make_mesh(shape, axes, devices=devs[:n],
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -18,7 +18,6 @@ import time
 import jax
 
 from repro import configs as cfgreg
-from repro.distributed import compat
 from repro.launch import roofline as rl
 from repro.launch.dryrun import (BIG_ARCHS, _cost_of, _depth_variant,
                                  _param_count, _active_frac, lower_lm_cell,
@@ -94,7 +93,7 @@ def measure_gnn(mesh, *, sampler="labor-0", compression="none",
         global_batch=meta["global_batch"], num_vertices=cfg.num_vertices,
         num_edges=int(cfg.num_vertices * cfg.avg_degree),
         feature_dim=cfg.feature_dim)
-    with compat.mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = engine.step_fn.lower(
             pspec, ospec, espec, ins["indptr"], ins["indices"],
             ins["features"], ins["labels"], ins["seeds"], ins["key"])

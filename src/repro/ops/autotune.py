@@ -1,11 +1,12 @@
 """Persistent autotuning for the frontier kernel family.
 
 The grid-parallel frontier kernels (`repro.kernels.frontier.parallel`)
-have one real tuning knob — the tile width of the per-tile bitonic
-networks — plus the coarser serial-vs-parallel choice (tiny problems
-fit in one serial scan; the serial dedup additionally has a hash-table
-load factor). The right settings depend on problem size and platform,
-so instead of hard-coding them this module:
+have one real tuning knob — the block width of their bitonic sort (the
+elements one grid step holds in VMEM) — plus the coarser
+serial-vs-parallel choice (the serial kernels run in interpret mode
+only; the serial dedup additionally has a hash-table load factor). The
+right settings depend on problem size and platform, so instead of
+hard-coding them this module:
 
   * buckets shapes to powers of two (``E=7000`` and ``E=8191`` share a
     tuning entry; re-tuning per exact shape would thrash),
@@ -19,12 +20,13 @@ so instead of hard-coding them this module:
 Cache file format (see docs/kernels.md):
 
     {"version": 1,
-     "entries": {"hash_dedup|cpu|E=16384,S=512":
-                     {"impl": "parallel", "tile": 512, "us": 1234.5},
+     "entries": {"hash_dedup|tpu|E=16384,S=512":
+                     {"impl": "parallel", "tile": 8192, "us": 1234.5},
                  ...}}
 
-The cache lives at ``$REPRO_AUTOTUNE_CACHE`` (or
-``~/.cache/repro/frontier_autotune.json``); a missing or corrupt file
+The cache lives at ``$REPRO_AUTOTUNE_CACHE`` or, by default, at
+``frontier_autotune.json`` in the root of the checkout — so dispatch
+depends only on files of the checkout; a missing or corrupt file
 degrades to the deterministic defaults in :data:`DEFAULT_PARAMS` —
 tuning is a perf knob, never a correctness one (every candidate is
 bit-exact by the parity contract, CI-gated in tests/test_frontier.py).
@@ -52,19 +54,17 @@ import sys
 import time
 from typing import Any, Dict, Optional
 
+from repro import CHECKOUT_DIR
+
 PRIMITIVES = ("hash_dedup", "compact", "compact_perm", "segment_select",
               "masked_cdf_draw")
 
-#: deterministic fallbacks when no cache entry exists — chosen from the
-#: committed BENCH_sampling.json point (parallel wins every primitive
-#: at the benchmarked sizes; 512 is the measured-best tile on cpu).
+#: deterministic fallbacks when no cache entry exists: the parallel
+#: kernels (the only ones that compile for TPU) with (64, 128) int32
+#: sort blocks — a 16M-element sort compiles in ~3 s on v5e at this
+#: width, against ~20 s at 32768 (untimed on the chip so far).
 DEFAULT_PARAMS: Dict[str, Dict[str, Any]] = {
-    "hash_dedup": {"impl": "parallel", "tile": 512},
-    "compact": {"impl": "parallel", "tile": 512},
-    "compact_perm": {"impl": "parallel"},
-    "segment_select": {"impl": "parallel"},
-    "masked_cdf_draw": {"impl": "parallel"},
-}
+    p: {"impl": "parallel", "tile": 8192} for p in PRIMITIVES}
 
 #: keys a cache entry may override (anything else — e.g. the recorded
 #: timing — is carried but ignored by dispatch)
@@ -77,12 +77,8 @@ _VERSION = 1
 
 
 def default_cache_path() -> str:
-    env = os.environ.get(CACHE_ENV)
-    if env:
-        return env
-    base = os.environ.get("XDG_CACHE_HOME",
-                          os.path.join(os.path.expanduser("~"), ".cache"))
-    return os.path.join(base, "repro", "frontier_autotune.json")
+    return (os.environ.get(CACHE_ENV)
+            or os.path.join(CHECKOUT_DIR, "frontier_autotune.json"))
 
 
 def _bucket(n: int) -> int:
@@ -203,17 +199,17 @@ def get_params(primitive: str, **shapes: int) -> Dict[str, Any]:
 
 
 def _candidates(primitive: str, smoke: bool):
-    tiles = (256, 512) if smoke else (128, 256, 512, 1024)
-    out = []
-    if primitive in ("hash_dedup", "compact"):
-        out += [{"impl": "parallel", "tile": t} for t in tiles]
-        if primitive == "hash_dedup":
-            loads = (2.0,) if smoke else (2.0, 4.0)
-            out += [{"impl": "serial", "table_load": l} for l in loads]
-        else:
-            out += [{"impl": "serial"}]
+    import jax
+
+    tiles = (1024, 2048) if smoke else (2048, 4096, 8192, 16384)
+    out = [{"impl": "parallel", "tile": t} for t in tiles]
+    if jax.default_backend() == "tpu":
+        return out  # the serial kernels do not compile for TPU
+    if primitive == "hash_dedup":
+        loads = (2.0,) if smoke else (2.0, 4.0)
+        out += [{"impl": "serial", "table_load": l} for l in loads]
     else:
-        out += [{"impl": "parallel"}, {"impl": "serial"}]
+        out += [{"impl": "serial"}]
     return out
 
 
@@ -286,22 +282,23 @@ def _build(primitive: str, params: Dict[str, Any], inputs):
     if primitive == "compact_perm":
         keys, valid, nk = inputs
         if impl == "parallel":
-            return lambda: par.compact_perm_block_parallel(keys, valid, nk,
-                                                           interpret=interp)
+            return lambda: par.compact_perm_block_parallel(
+                keys, valid, nk, tile=params["tile"], interpret=interp)
         return lambda: serial.compact_perm_block(keys, valid, nk,
                                                  interpret=interp)
     if primitive == "segment_select":
         keys, slot, mask, seg_start, take, ns, mt = inputs
         if impl == "parallel":
             return lambda: par.segment_select_block_parallel(
-                keys, slot, mask, seg_start, take, ns, interpret=interp)
+                keys, slot, mask, seg_start, take, ns, tile=params["tile"],
+                interpret=interp)
         return lambda: serial.segment_select_block(keys, slot, mask, take,
                                                    ns, mt, interpret=interp)
     if primitive == "masked_cdf_draw":
         p, valid, u = inputs
         if impl == "parallel":
             return lambda: par.masked_cdf_draw_block_parallel(
-                p, valid, u, interpret=interp)
+                p, valid, u, tile=params["tile"], interpret=interp)
         return lambda: serial.masked_cdf_draw_block(p, valid, u,
                                                     interpret=interp)
     raise ValueError(primitive)

@@ -186,8 +186,7 @@ def edge_softmax(blk: SampledLayer, logits: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# frontier primitives — serial VMEM kernels (kernels/frontier); integer
-# data motion, so no custom VJPs are needed
+# frontier primitives — integer data motion, so no custom VJPs are needed
 # ---------------------------------------------------------------------------
 
 # Each frontier primitive resolves its tuning params (serial vs
@@ -195,12 +194,28 @@ def edge_softmax(blk: SampledLayer, logits: jax.Array) -> jax.Array:
 # shapes are static under jit, so the cache lookup never enters the
 # traced program and a re-tune only changes which kernel gets traced.
 # Both implementations are bit-exact by contract (tests/test_frontier.py)
-# so the choice is pure perf.
+# so the choice is pure perf. The serial kernels (kernels/frontier/
+# frontier.py) hold (N, 1) columns and scalar VMEM stores the TPU
+# compiler refuses: they run in interpret mode only.
+
+def _params(primitive: str, **shapes: int) -> dict:
+    p = autotune.get_params(primitive, **shapes)
+    if p["impl"] == "serial" and not interpret_mode():
+        raise ValueError(
+            f"frontier primitive {primitive!r}: impl='serial' (from "
+            f"{autotune.IMPL_ENV} or the tuning cache) does not compile for "
+            f"TPU; use impl='parallel'")
+    return p
+
+
+def _tile(p: dict) -> int:
+    return int(p.get("tile", frontier_par.DEFAULT_TILE))
+
 
 def hash_dedup(values: jax.Array, mask: jax.Array,
                seeds: Optional[jax.Array], new_cap: int):
-    p = autotune.get_params("hash_dedup", E=values.shape[0],
-                            S=0 if seeds is None else seeds.shape[0])
+    p = _params("hash_dedup", E=values.shape[0],
+                S=0 if seeds is None else seeds.shape[0])
     if p["impl"] == "serial":
         s = 0 if seeds is None else seeds.shape[0]
         load = float(p.get("table_load", 2.0))
@@ -210,33 +225,33 @@ def hash_dedup(values: jax.Array, mask: jax.Array,
                                              table_cap=cap,
                                              interpret=interpret_mode())
     return frontier_par.hash_dedup_block_parallel(
-        values, mask, seeds, new_cap, tile=int(p.get("tile", 512)),
+        values, mask, seeds, new_cap, tile=_tile(p),
         interpret=interpret_mode())
 
 
 def compact(flags: jax.Array, cap: int):
-    p = autotune.get_params("compact", E=flags.shape[0])
+    p = _params("compact", E=flags.shape[0])
     if p["impl"] == "serial":
         return frontier_ops.compact_block(flags, cap,
                                           interpret=interpret_mode())
     return frontier_par.compact_block_parallel(
-        flags, cap, tile=int(p.get("tile", 512)), interpret=interpret_mode())
+        flags, cap, tile=_tile(p), interpret=interpret_mode())
 
 
 def compact_perm(keys: jax.Array, valid: jax.Array,
                  num_keys: int) -> jax.Array:
-    p = autotune.get_params("compact_perm", E=keys.shape[0], S=num_keys)
+    p = _params("compact_perm", E=keys.shape[0], S=num_keys)
     if p["impl"] == "serial":
         return frontier_ops.compact_perm_block(keys, valid, num_keys,
                                                interpret=interpret_mode())
     return frontier_par.compact_perm_block_parallel(
-        keys, valid, num_keys, interpret=interpret_mode())
+        keys, valid, num_keys, tile=_tile(p), interpret=interpret_mode())
 
 
 def segment_select(keys: jax.Array, slot: jax.Array, mask: jax.Array,
                    seg_start: jax.Array, take: jax.Array, num_seeds: int,
                    max_take: int) -> jax.Array:
-    p = autotune.get_params("segment_select", E=keys.shape[0], S=num_seeds)
+    p = _params("segment_select", E=keys.shape[0], S=num_seeds)
     if p["impl"] == "serial":
         # the serial kernel re-derives segment bounds from its scan and
         # never reads seg_start; the parallel sort/select needs it
@@ -244,16 +259,15 @@ def segment_select(keys: jax.Array, slot: jax.Array, mask: jax.Array,
                                                  num_seeds, max_take,
                                                  interpret=interpret_mode())
     return frontier_par.segment_select_block_parallel(
-        keys, slot, mask, seg_start, take, num_seeds,
+        keys, slot, mask, seg_start, take, num_seeds, tile=_tile(p),
         interpret=interpret_mode())
 
 
 def masked_cdf_draw(p: jax.Array, valid: jax.Array,
                     u: jax.Array) -> jax.Array:
-    params = autotune.get_params("masked_cdf_draw", E=p.shape[0],
-                                 S=u.shape[0])
+    params = _params("masked_cdf_draw", E=p.shape[0], S=u.shape[0])
     if params["impl"] == "serial":
         return frontier_ops.masked_cdf_draw_block(p, valid, u,
                                                   interpret=interpret_mode())
     return frontier_par.masked_cdf_draw_block_parallel(
-        p, valid, u, interpret=interpret_mode())
+        p, valid, u, tile=_tile(params), interpret=interpret_mode())

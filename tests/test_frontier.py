@@ -38,6 +38,15 @@ from repro.kernels.frontier import ops as frontier_kernel_ops
 BACKENDS = ("xla", "pallas")
 
 
+@pytest.fixture(autouse=True)
+def _release_compiled_programs_per_test():
+    """Nearly every case here compiles new shapes (hypothesis sweeps,
+    tile-boundary grids): release them per test, not per file, or the
+    file alone reaches the process's memory-map limit (see conftest)."""
+    yield
+    jax.clear_caches()
+
+
 @pytest.fixture(scope="module")
 def ds():
     return generate(DatasetSpec("mini", 3000, 14.0, 16, 5, 0.5, 0.2, 0.6,
@@ -515,7 +524,7 @@ def _dedup_equal(a, b, msg=""):
 @pytest.mark.parametrize("E", TILE_EDGE_SIZES)
 @pytest.mark.parametrize("tile", TINY_TILES)
 def test_parallel_dedup_parity_across_tile_boundaries(E, tile):
-    """Forced tiny tiles: the per-tile stripes + cooperative merge must
+    """Forced tiny sort blocks: the multi-block sort passes must
     reproduce the serial kernel and the XLA ref bit for bit at sizes
     below/at/past every tile boundary (new_cap = E: never gives up, so
     the FULL contract is in force)."""
@@ -534,28 +543,51 @@ def test_parallel_dedup_parity_across_tile_boundaries(E, tile):
 
 
 def test_parallel_dedup_stripe_overflow_propagates_across_tiles():
-    """A stripe too small for ONE tile's unique count must surface as
-    the overflow flag even when the merge output fits new_cap — and the
-    flag must propagate from whichever grid step tripped it."""
-    # every value unique: each 8-wide tile carries 8 uniques
+    """More distinct new values than ``new_cap`` must surface as the
+    overflow flag, with ``new`` truncated to the smallest ``new_cap``
+    exactly like the reference — whichever sort block the excess comes
+    from, including only the last one."""
     vals = jnp.asarray(np.arange(64, dtype=np.int32))
     mask = jnp.ones((64,), bool)
-    r = frontier_par.hash_dedup_block_parallel(vals, mask, None, 64,
-                                               tile=8, stripe_cap=2,
-                                               interpret=True)
+    r = frontier_par.hash_dedup_block_parallel(vals, mask, None, 16,
+                                               tile=8, interpret=True)
     assert bool(r.overflow)
-    # overflow arising ONLY in the last tile still propagates
+    _dedup_equal(r, frontier_ref.hash_dedup(vals, mask, None, 16), "all")
+    # excess arising ONLY in the last block still propagates
     v2 = np.zeros(64, np.int32)
-    v2[56:] = np.arange(100, 108)          # 8 uniques, final tile only
+    v2[56:] = np.arange(100, 108)          # 8 uniques, final block only
     r2 = frontier_par.hash_dedup_block_parallel(
-        jnp.asarray(v2), mask, None, 64, tile=8, stripe_cap=4,
-        interpret=True)
+        jnp.asarray(v2), mask, None, 4, tile=8, interpret=True)
     assert bool(r2.overflow)
-    # same inputs, default stripe (== tile, provably sufficient): exact
+    _dedup_equal(r2, frontier_ref.hash_dedup(jnp.asarray(v2), mask, None, 4),
+                 "last block")
+    # room for every unique: exact and flag-free
     r3 = frontier_par.hash_dedup_block_parallel(vals, mask, None, 64,
                                                 tile=8, interpret=True)
     assert not bool(r3.overflow)
     np.testing.assert_array_equal(np.asarray(r3.new), np.asarray(vals))
+
+
+@pytest.mark.parametrize("n,tile,n_keys,n_words", [
+    (8, 8, 1, 1), (64, 8, 1, 2), (64, 16, 2, 3), (256, 32, 2, 2),
+    (1024, 64, 1, 1), (2048, 1024, 2, 3)])
+def test_sort_words_matches_lexsort(n, tile, n_keys, n_words):
+    """The one kernel under every parallel primitive: in-block stages,
+    cross-block grid passes and in-block merges must sort word tuples
+    lexicographically by the key words, duplicates included, and carry
+    the payload words without dropping or repeating a tuple."""
+    rng = np.random.default_rng(n + tile + n_keys)
+    keys = [rng.integers(-5, 7, n).astype(np.int32) for _ in range(n_keys)]
+    pays = [rng.integers(0, 2**31 - 1, n).astype(np.int32)
+            for _ in range(n_words - n_keys)]
+    out = frontier_par.sort_words([jnp.asarray(w) for w in keys + pays],
+                                  n_keys, tile=tile, interpret=True)
+    out = [np.asarray(o) for o in out]
+    order = np.lexsort(keys[::-1])
+    for k, o in zip(keys, out):
+        np.testing.assert_array_equal(o, k[order])
+    assert sorted(zip(*[w.tolist() for w in keys + pays])) == \
+        sorted(zip(*[o.tolist() for o in out]))
 
 
 @settings(max_examples=15, deadline=None)
@@ -645,6 +677,17 @@ def test_registry_dispatch_parallel_serial_switch(monkeypatch):
         monkeypatch.setenv(autotune.IMPL_ENV, impl)
         got = O.hash_dedup(vals, mask, seeds, 300, backend="pallas")
         _dedup_equal(got, ref, impl)
+
+
+def test_registry_refuses_serial_kernels_when_compiled(monkeypatch):
+    """The serial kernels do not compile for TPU: asking for them where
+    kernels are compiled must fail loudly at dispatch, never run."""
+    from repro.ops import autotune
+    from repro.ops import pallas as pallas_backend
+    monkeypatch.setenv(autotune.IMPL_ENV, "serial")
+    monkeypatch.setattr(pallas_backend, "interpret_mode", lambda: False)
+    with pytest.raises(ValueError, match="does not compile for TPU"):
+        pallas_backend.compact(jnp.ones((16,), bool), 8)
 
 
 # ---------------------------------------------------------------------------
