@@ -54,6 +54,7 @@ class SampledLayer:
     num_seeds: jax.Array    # int32[] real seed count
     num_next: jax.Array     # int32[] real next_seeds count
     num_edges: jax.Array    # int32[] real sampled edge count
+    num_expanded: jax.Array  # int32[] real in-edge count of the seeds
     overflow: jax.Array     # bool[] any cap exceeded while building this layer
 
     @property
@@ -82,10 +83,16 @@ def overflow_flags(blocks: Sequence["SampledLayer"]) -> jax.Array:
 def sampled_counts(blocks: Sequence["SampledLayer"]) -> dict:
     """Device-side sampling size metrics for a multi-layer block list:
     ``sampled_v`` = |V^3|-style vertex count of the deepest layer,
-    ``sampled_e`` = total sampled edges across layers."""
+    ``sampled_e`` = total sampled edges across layers, ``layer_counts``
+    = int32[num_layers, 3] of (expanded in-edges, sampled edges, next
+    vertices) per layer — the real sizes beside each layer's static
+    ``expand_cap``, ``edge_cap`` and ``vertex_cap``."""
     return {
         "sampled_v": blocks[-1].num_next,
         "sampled_e": sum(b.num_edges for b in blocks),
+        "layer_counts": jnp.stack([
+            jnp.stack([b.num_expanded, b.num_edges, b.num_next])
+            for b in blocks]).astype(jnp.int32),
     }
 
 
@@ -412,6 +419,7 @@ def build_block(seeds: jax.Array, exp: dict, include: jax.Array,
         num_seeds=num_seeds,
         num_next=num_seeds + dd.num_new,
         num_edges=num_sampled,
+        num_expanded=exp["total"],
         overflow=overflow,
     )
 
@@ -484,5 +492,6 @@ def build_block_dense(num_vertices: int, seeds: jax.Array, exp: dict,
         num_seeds=num_seeds,
         num_next=num_seeds + num_new,
         num_edges=num_sampled,
+        num_expanded=exp["total"],
         overflow=overflow,
     )

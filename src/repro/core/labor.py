@@ -32,6 +32,7 @@ from repro.core.interface import (LayerCaps, SampledLayer, Sampler,
                                   SamplerSpec, build_block)
 from repro.graph.csr import Graph, expand_seed_edges
 from repro.ops import frontier as frontier_ops
+from repro.runtime import spans
 
 CONVERGE = -1  # importance_iters value for LABOR-*
 
@@ -324,15 +325,16 @@ def sample_with_salts(cfg: LaborConfig, caps: Sequence[LayerCaps],
     blocks = []
     cur = seeds
     for layer, (k, lcaps) in enumerate(zip(cfg.fanouts, caps)):
-        blk = sample_layer(
-            graph, cur, salts[layer], k, lcaps,
-            importance_iters=cfg.importance_iters,
-            per_edge_rng=cfg.per_edge_rng,
-            exact_k=cfg.exact_k,
-            converge_tol=cfg.converge_tol,
-            converge_max_iters=cfg.converge_max_iters,
-            fast_solve=cfg.fast_solve,
-        )
+        with jax.named_scope(spans.layer(layer)):
+            blk = sample_layer(
+                graph, cur, salts[layer], k, lcaps,
+                importance_iters=cfg.importance_iters,
+                per_edge_rng=cfg.per_edge_rng,
+                exact_k=cfg.exact_k,
+                converge_tol=cfg.converge_tol,
+                converge_max_iters=cfg.converge_max_iters,
+                fast_solve=cfg.fast_solve,
+            )
         blocks.append(blk)
         cur = blk.next_seeds
     return blocks

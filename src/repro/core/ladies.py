@@ -41,6 +41,7 @@ from repro.core.interface import (LayerCaps, SampledLayer, Sampler,
                                   SamplerSpec, build_block)
 from repro.graph.csr import Graph, expand_seed_edges
 from repro.ops import frontier as frontier_ops
+from repro.runtime import spans
 
 
 def _edge_contrib(exp: dict) -> jax.Array:
@@ -209,8 +210,9 @@ class LadiesSampler(Sampler):
         cur = seeds
         for layer, (n, caps) in enumerate(zip(self.config.layer_sizes,
                                               self.spec.caps)):
-            blk = sample_layer_ladies(graph, cur, salts[layer], n, caps,
-                                      poisson=self.config.poisson)
+            with jax.named_scope(spans.layer(layer)):
+                blk = sample_layer_ladies(graph, cur, salts[layer], n, caps,
+                                          poisson=self.config.poisson)
             blocks.append(blk)
             cur = blk.next_seeds
         return blocks

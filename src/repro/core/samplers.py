@@ -53,6 +53,7 @@ from repro.core.interface import (LayerCaps, SampledLayer, Sampler,
 from repro.core.labor import CONVERGE, LaborConfig, LaborSampler
 from repro.core.ladies import LadiesConfig, LadiesSampler
 from repro.graph.csr import Graph, expand_seed_edges
+from repro.runtime import spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,10 +68,11 @@ class FullSampler(Sampler):
         del salts  # deterministic: include everything
         blocks = []
         cur = seeds
-        for caps in self.spec.caps:
-            exp = expand_seed_edges(graph, cur, caps.expand_cap)
-            inv_p = jnp.ones((caps.expand_cap,), jnp.float32)  # p_ts = 1
-            blk = build_block(cur, exp, exp["mask"], inv_p, caps)
+        for layer, caps in enumerate(self.spec.caps):
+            with jax.named_scope(spans.layer(layer)):
+                exp = expand_seed_edges(graph, cur, caps.expand_cap)
+                inv_p = jnp.ones((caps.expand_cap,), jnp.float32)  # p_ts = 1
+                blk = build_block(cur, exp, exp["mask"], inv_p, caps)
             blocks.append(blk)
             cur = blk.next_seeds
         return blocks

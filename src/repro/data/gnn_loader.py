@@ -28,9 +28,12 @@ from repro.runtime.guard import RetryPolicy
 
 @dataclasses.dataclass
 class LoaderStats:
-    batches: int = 0
     overflow_retries: int = 0
     overflow_replays: int = 0   # fused path: batches replayed one step late
+    # entry l: polled batches whose overflow flag l was set — sampling
+    # layer l for l < num_layers; on a mesh the later entries are the
+    # all-to-all buffers, in the step's flag order
+    overflow_by_layer: list = dataclasses.field(default_factory=list)
     stragglers_skipped: int = 0
     # pipelined path: in-flight batches re-sampled after a replay grew
     # the cap schedule (runtime/pipeline.py)
@@ -145,7 +148,6 @@ class PrefetchIterator:
                 continue
             if item is self._done:
                 raise StopIteration
-            self.stats.batches += 1
             return item
 
 
@@ -242,7 +244,12 @@ class OverflowLedger:
         if entry is None:
             return None
         tag, flags = entry
-        if bool(np.any(np.asarray(flags))):
-            self.stats.overflow_replays += 1
-            return tag
-        return None
+        flags = np.asarray(flags).reshape(-1)
+        if not flags.any():
+            return None
+        self.stats.overflow_replays += 1
+        by = self.stats.overflow_by_layer
+        by.extend([0] * (flags.size - len(by)))
+        for i in np.flatnonzero(flags):
+            by[i] += 1
+        return tag

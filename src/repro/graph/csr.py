@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import spans
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -136,6 +138,12 @@ def expand_seed_edges(graph: Graph, seeds: jax.Array, edge_cap: int,
     ``total <= edge_cap`` (the data pipeline sizes caps so overflow is
     rare and re-tries with a bigger bucket when it happens).
     """
+    with jax.named_scope(spans.EXPAND_SEED_EDGES):
+        return _expand(graph, seeds, edge_cap, seed_rows)
+
+
+def _expand(graph: Graph, seeds: jax.Array, edge_cap: int,
+            seed_rows: Optional[jax.Array]):
     S = seeds.shape[0]
     valid = seeds >= 0
     safe_seeds = jnp.where(valid, seeds if seed_rows is None else seed_rows, 0)

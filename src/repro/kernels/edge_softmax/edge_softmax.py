@@ -130,6 +130,9 @@ def edge_softmax_stats(logits: jax.Array, dst: jax.Array, num_rows: int,
             jax.ShapeDtypeStruct((num_rows, Hp), jnp.float32),
             jax.ShapeDtypeStruct((num_rows, Hp), jnp.float32),
         ],
+        # no name=: a kernel name becomes an op-path segment between
+        # jit(edge_softmax_stats) and pallas_call, the path the
+        # benchmark's edge_softmax_roofline reads
         interpret=interpret,
     )(row_block, first, dst_local, logits)
     return m, s

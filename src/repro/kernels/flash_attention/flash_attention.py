@@ -108,6 +108,6 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, softcap=None,
         out_specs=pl.BlockSpec((pl.squeezed, pl.squeezed, bq, hd),
                                lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="flash_attention",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

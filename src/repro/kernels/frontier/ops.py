@@ -49,7 +49,7 @@ def _dedup_collect(values, mask, seeds, new_cap: int, table_cap: int,
         K.dedup_kernel,
         out_shape=(_i32((new_cap, 1)), _i32((1, 1)), _i32((1, 1))),
         scratch_shapes=[pltpu.VMEM((table_cap, 1), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret, name="frontier_dedup_collect",
     )(_col(values.astype(jnp.int32)), _col(mask.astype(jnp.int32)),
       _col(seeds.astype(jnp.int32)))
 
@@ -62,7 +62,7 @@ def _dedup_lookup(next_vals, values, mask, table_cap: int, interpret: bool):
         out_shape=_i32((E, 1)),
         scratch_shapes=[pltpu.VMEM((table_cap, 1), jnp.int32),
                         pltpu.VMEM((table_cap, 1), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret, name="frontier_dedup_lookup",
     )(_col(next_vals.astype(jnp.int32)), _col(values.astype(jnp.int32)),
       _col(mask.astype(jnp.int32)))
 
@@ -112,7 +112,7 @@ def compact_block(flags: jax.Array, cap: int, interpret: bool = False):
     sel, num = pl.pallas_call(
         K.compact_kernel,
         out_shape=(_i32((cap, 1)), _i32((1, 1))),
-        interpret=interpret,
+        interpret=interpret, name="frontier_compact",
     )(_col(flags.astype(jnp.int32)))
     num = num[0, 0]
     emask = jnp.arange(cap) < jnp.minimum(num, cap)
@@ -133,7 +133,7 @@ def compact_perm_block(keys: jax.Array, valid: jax.Array, num_keys: int,
         K.perm_kernel,
         out_shape=_i32((E, 1)),
         scratch_shapes=[pltpu.VMEM((num_keys + 2, 1), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret, name="frontier_compact_perm",
     )(_col(eff.astype(jnp.int32)))
     return perm[:, 0]
 
@@ -154,7 +154,7 @@ def segment_select_block(keys: jax.Array, slot: jax.Array, mask: jax.Array,
         scratch_shapes=[pltpu.VMEM((max(k, 1), 1), jnp.float32),
                         pltpu.VMEM((num_seeds, 1), jnp.float32),
                         pltpu.VMEM((num_seeds, 1), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret, name="frontier_segment_select",
     )(_col(keys.astype(jnp.float32)), _col(slot_in.astype(jnp.int32)),
       _col(take.astype(jnp.int32)))
     return inc[:, 0] != 0
@@ -170,6 +170,6 @@ def masked_cdf_draw_block(p: jax.Array, valid: jax.Array, u: jax.Array,
     out = pl.pallas_call(
         K.search_kernel,
         out_shape=_i32((u.shape[0], 1)),
-        interpret=interpret,
+        interpret=interpret, name="frontier_cdf_search",
     )(_col(cdf.astype(jnp.float32)), _col(u.astype(jnp.float32)))
     return out[:, 0]

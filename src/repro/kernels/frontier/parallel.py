@@ -204,7 +204,7 @@ def sort_words(words: Sequence[jax.Array], n_keys: int,
     x = pl.pallas_call(
         functools.partial(_sort_blocks_kernel, n_words=nw, n_keys=n_keys),
         grid=(nb,), in_specs=[own] * nw, out_specs=[own] * nw,
-        out_shape=shape, interpret=interpret,
+        out_shape=shape, interpret=interpret, name="frontier_sort_blocks",
     )(*x)
     log_t, log_n = tile.bit_length() - 1, n.bit_length() - 1
     if log_n == log_t:
@@ -217,13 +217,13 @@ def sort_words(words: Sequence[jax.Array], n_keys: int,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(nb,),
             in_specs=[own, partner] * nw, out_specs=[own] * nw),
-        out_shape=shape, interpret=interpret)
+        out_shape=shape, interpret=interpret, name="frontier_sort_cross")
     merge = pl.pallas_call(
         functools.partial(_merge_blocks_kernel, n_words=nw, n_keys=n_keys),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(nb,),
             in_specs=[own] * nw, out_specs=[own] * nw),
-        out_shape=shape, interpret=interpret)
+        out_shape=shape, interpret=interpret, name="frontier_sort_merge")
 
     def stage(st, x):
         st_arr = jnp.reshape(st, (1,)).astype(jnp.int32)

@@ -96,7 +96,7 @@ def gather_rows_sorted(rows: jax.Array, dst: jax.Array,
             out_specs=pl.BlockSpec((be, bf), lambda f, c, rb: (c, f)),
         ),
         out_shape=jax.ShapeDtypeStruct((E, F), rows.dtype),
-        interpret=interpret,
+        interpret=interpret, name="gather_rows_sorted",
     )(row_block, dst_local, rows)
     return out
 
@@ -139,6 +139,9 @@ def spmm_sorted(messages: jax.Array, dst: jax.Array, num_rows: int,
             out_specs=pl.BlockSpec((bs, bf), lambda f, c, rb, fs: (rb[c], f)),
         ),
         out_shape=jax.ShapeDtypeStruct((num_rows, F), messages.dtype),
+        # no name=: a kernel name becomes an op-path segment between
+        # jit(spmm_sorted) and pallas_call, the path the benchmark's
+        # spmm_roofline reads
         interpret=interpret,
     )(row_block, first, dst_local, messages)
     return out

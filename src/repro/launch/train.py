@@ -127,6 +127,7 @@ def main():
             "stragglers_skipped": out["stats"].stragglers_skipped,
             "overflow_retries": out["stats"].overflow_retries,
             "overflow_replays": out["stats"].overflow_replays,
+            "overflow_by_layer": out["stats"].overflow_by_layer,
         }
         if "guard_stats" in out:
             gs = out["guard_stats"]

@@ -8,14 +8,17 @@ reference scans/sorts over cap-sized buffers, ``"pallas"`` serial VMEM
 kernels; ``"auto"``/None picks by platform exactly like the model
 primitives). The shared contract — and the point of the family — is
 O(cap) cost and memory: nothing here allocates or touches a buffer
-sized by the graph's vertex count.
+sized by the graph's vertex count. Each primitive runs under a
+``jax.named_scope`` of its own name (``repro.runtime.spans``), so its
+device ops carry the same op-path segment whatever backend or private
+wrapper serves it.
 
 Import-graph note: the samplers (``repro.core``) import this module at
 module scope, which runs the ops package __init__ and registers the
 built-in backends. That is cycle-free because no ops module imports
 ``repro.core`` at module scope anymore (SampledLayer appears only
 under TYPE_CHECKING) — this module itself depends only on
-``repro.ops.backend``.
+``repro.ops.backend`` and the name constants of ``repro.runtime.spans``.
 """
 from __future__ import annotations
 
@@ -24,6 +27,7 @@ from typing import Optional
 import jax
 
 from repro.ops.backend import get_backend
+from repro.runtime import spans
 
 
 def hash_dedup(values: jax.Array, mask: jax.Array,
@@ -35,7 +39,8 @@ def hash_dedup(values: jax.Array, mask: jax.Array,
     :class:`repro.kernels.frontier.ref.DedupResult`; ``overflow`` feeds
     the doubled-caps replay protocol. Replaces the three dense V-sized
     membership/position buffers of the old ``build_block``."""
-    return get_backend(backend).hash_dedup(values, mask, seeds, new_cap)
+    with jax.named_scope(spans.HASH_DEDUP):
+        return get_backend(backend).hash_dedup(values, mask, seeds, new_cap)
 
 
 def compact(flags: jax.Array, cap: int, *,
@@ -43,7 +48,8 @@ def compact(flags: jax.Array, cap: int, *,
     """Order-preserving stream compaction: (sel int32[cap], emask
     bool[cap], num int32[]) — the indices of the first ``cap`` set
     flags, matching ``jnp.nonzero(flags, size=cap, fill_value=0)``."""
-    return get_backend(backend).compact(flags, cap)
+    with jax.named_scope(spans.COMPACT):
+        return get_backend(backend).compact(flags, cap)
 
 
 def compact_perm(keys: jax.Array, valid: jax.Array, num_keys: int, *,
@@ -51,7 +57,8 @@ def compact_perm(keys: jax.Array, valid: jax.Array, num_keys: int, *,
     """The compaction family's ordering face: a STABLE permutation
     sorting entries by ascending key (keys in [-1, num_keys); invalid
     last) — ``SampledLayer.src_perm`` without a per-step argsort."""
-    return get_backend(backend).compact_perm(keys, valid, num_keys)
+    with jax.named_scope(spans.COMPACT_PERM):
+        return get_backend(backend).compact_perm(keys, valid, num_keys)
 
 
 def segment_select(keys: jax.Array, slot: jax.Array, mask: jax.Array,
@@ -62,8 +69,9 @@ def segment_select(keys: jax.Array, slot: jax.Array, mask: jax.Array,
     over the segment-contiguous ``expand_seed_edges`` layout — the
     sequential-Poisson (§A.3) inclusion set without a global lexsort.
     ``max_take`` is the static fanout bound (>= every take[s])."""
-    return get_backend(backend).segment_select(keys, slot, mask, seg_start,
-                                               take, num_seeds, max_take)
+    with jax.named_scope(spans.SEGMENT_SELECT):
+        return get_backend(backend).segment_select(
+            keys, slot, mask, seg_start, take, num_seeds, max_take)
 
 
 def masked_cdf_draw(p: jax.Array, valid: jax.Array, u: jax.Array, *,
@@ -71,4 +79,5 @@ def masked_cdf_draw(p: jax.Array, valid: jax.Array, u: jax.Array, *,
     """Inverse-CDF draws over the valid entries of ``p`` in one
     cap-bounded pass, normalized by the CDF's own final value so
     float32 accumulation error can never index out of range."""
-    return get_backend(backend).masked_cdf_draw(p, valid, u)
+    with jax.named_scope(spans.MASKED_CDF_DRAW):
+        return get_backend(backend).masked_cdf_draw(p, valid, u)

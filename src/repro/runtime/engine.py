@@ -65,6 +65,7 @@ from repro import ops as graph_ops
 from repro.core.interface import Sampler, overflow_flags, sampled_counts
 from repro.data.gnn_loader import (LoaderStats, OverflowLedger,
                                    SamplingOverflowError)
+from repro.runtime import spans
 from repro.runtime.guard import (GuardConfig, RetryPolicy, guard_update,
                                  init_guard_state)
 from repro.distributed import compression as comp
@@ -495,34 +496,66 @@ class TrainEngine:
                            else self._build_distributed(train=False))
         return self._infer
 
-    def _build_single_train(self):
+    def _single_stages(self):
+        """The single-host step's stages as traceable functions, each
+        under its stage scope (``repro.runtime.spans``): the one
+        definition the fused program and the staged programs share.
+
+        ``sample(graph, seeds, key) -> blocks``,
+        ``gather(features, labels_all, blocks) -> (feats, labels)``,
+        ``epilogue(params, opt, gstate, blocks, feats, labels) ->
+        (params, opt, gstate, metrics)``: the loss and its gradient
+        under ``model``, then the Adam update, the overflow/guard gate
+        and the step's metrics under ``optimizer``."""
         sampler, apply_fn = self.sampler, self.model_apply
         opt_cfg, backend, guard_cfg = self.opt_cfg, self.backend, self.guard
 
-        def body(params, opt_state, gstate, graph, features, labels_all,
-                 seeds, key):
-            blocks = sampler.sample(graph, seeds, sampler.spec.salts(key))
-            feats = gather_feats(features, blocks[-1])
-            labels = labels_all[jnp.where(seeds >= 0, seeds, 0)]
-            (loss, acc), grads = jax.value_and_grad(
-                lambda p: gnn_loss_fn(apply_fn, p, blocks, feats, labels,
-                                      backend),
-                has_aux=True,
-            )(params)
-            new_params, new_opt, m = adam.apply_updates(params, grads,
-                                                        opt_state, opt_cfg)
-            ovf = overflow_flags(blocks)
-            any_ovf = jnp.any(ovf)
-            bad, gstate_out, gm = _guard_gate(guard_cfg, loss, grads, gstate,
-                                              any_ovf)
-            gate = lambda new, old: jnp.where(bad, old, new)
-            params_out = jax.tree.map(gate, new_params, params)
-            opt_out = jax.tree.map(gate, new_opt, opt_state)
-            m.update(loss=loss, acc=acc, overflow=ovf, **gm,
-                     **sampled_counts(blocks))
+        def sample(graph, seeds, key):
+            with jax.named_scope(spans.SAMPLE):
+                return tuple(sampler.sample(graph, seeds,
+                                            sampler.spec.salts(key)))
+
+        def gather(features, labels_all, blocks):
+            with jax.named_scope(spans.FEATURE_GATHER):
+                feats = gather_feats(features, blocks[-1])
+                seeds = blocks[0].seeds
+                labels = labels_all[jnp.where(seeds >= 0, seeds, 0)]
+            return feats, labels
+
+        def epilogue(params, opt_state, gstate, blocks, feats, labels):
+            def loss_fn(p):
+                with jax.named_scope(spans.MODEL):
+                    return gnn_loss_fn(apply_fn, p, blocks, feats, labels,
+                                       backend)
+
+            (loss, acc), grads = jax.value_and_grad(loss_fn,
+                                                    has_aux=True)(params)
+            with jax.named_scope(spans.OPTIMIZER):
+                new_params, new_opt, m = adam.apply_updates(
+                    params, grads, opt_state, opt_cfg)
+                ovf = overflow_flags(blocks)
+                any_ovf = jnp.any(ovf)
+                bad, gstate_out, gm = _guard_gate(guard_cfg, loss, grads,
+                                                  gstate, any_ovf)
+                gate = lambda new, old: jnp.where(bad, old, new)
+                params_out = jax.tree.map(gate, new_params, params)
+                opt_out = jax.tree.map(gate, new_opt, opt_state)
+                m.update(loss=loss, acc=acc, overflow=ovf, **gm,
+                         **sampled_counts(blocks))
             return params_out, opt_out, gstate_out, m
 
-        if guard_cfg is None:
+        return sample, gather, epilogue
+
+    def _build_single_train(self):
+        sample, gather, epilogue = self._single_stages()
+
+        def body(params, opt_state, gstate, graph, features, labels_all,
+                 seeds, key):
+            blocks = sample(graph, seeds, key)
+            feats, labels = gather(features, labels_all, blocks)
+            return epilogue(params, opt_state, gstate, blocks, feats, labels)
+
+        if self.guard is None:
             @partial(jax.jit, donate_argnums=(0, 1))
             def step(params, opt_state, graph, features, labels_all, seeds,
                      key):
@@ -646,44 +679,14 @@ class TrainEngine:
         return self._staged
 
     def _build_single_stages(self) -> StagedFns:
-        sampler, apply_fn = self.sampler, self.model_apply
-        opt_cfg, backend, guard_cfg = self.opt_cfg, self.backend, self.guard
-
-        @jax.jit
-        def sample(graph, seeds, key):
-            # salt-only: stateless in params, so batch t+1's frontier
-            # can be in flight while batch t trains. Same trace as the
-            # sampling half of the fused program -> bit-identical sets.
-            return tuple(sampler.sample(graph, seeds, sampler.spec.salts(key)))
-
-        def _gather(features, labels_all, blocks):
-            feats = gather_feats(features, blocks[-1])
-            seeds = blocks[0].seeds
-            labels = labels_all[jnp.where(seeds >= 0, seeds, 0)]
-            return feats, labels
-
+        # sample is salt-only: stateless in params, so batch t+1's
+        # frontier can be in flight while batch t trains. Same trace as
+        # the sampling half of the fused program -> bit-identical sets.
+        _sample, _gather, _epilogue = self._single_stages()
+        sample = jax.jit(_sample)
         gather = jax.jit(_gather)
 
-        def _epilogue(params, opt_state, gstate, blocks, feats, labels):
-            (loss, acc), grads = jax.value_and_grad(
-                lambda p: gnn_loss_fn(apply_fn, p, blocks, feats, labels,
-                                      backend),
-                has_aux=True,
-            )(params)
-            new_params, new_opt, m = adam.apply_updates(params, grads,
-                                                        opt_state, opt_cfg)
-            ovf = overflow_flags(blocks)
-            any_ovf = jnp.any(ovf)
-            bad, gstate_out, gm = _guard_gate(guard_cfg, loss, grads, gstate,
-                                              any_ovf)
-            gate = lambda new, old: jnp.where(bad, old, new)
-            params_out = jax.tree.map(gate, new_params, params)
-            opt_out = jax.tree.map(gate, new_opt, opt_state)
-            m.update(loss=loss, acc=acc, overflow=ovf, **gm,
-                     **sampled_counts(blocks))
-            return params_out, opt_out, gstate_out, m
-
-        if guard_cfg is None:
+        if self.guard is None:
             @partial(jax.jit, donate_argnums=(0, 1))
             def compute(params, opt_state, blocks, feats, labels):
                 p, o, _, m = _epilogue(params, opt_state, None, blocks,
@@ -1088,32 +1091,32 @@ class TrainEngine:
 
     def _dispatch(self, params, state: EngineState, data: EngineData, seeds,
                   key):
-        self.dispatches += 1
-        if self.mesh is None:
+        with jax.profiler.TraceAnnotation(spans.ENGINE_DISPATCH):
+            self.dispatches += 1
+            if self.mesh is None:
+                if self.guard is None:
+                    params, opt, m = self.step_fn(
+                        params, state.opt, data.graph, data.features,
+                        data.labels, seeds, key)
+                    return params, EngineState(opt=opt, err=state.err), m
+                params, opt, g, m = self.step_fn(
+                    params, state.opt, state.guard, data.graph,
+                    data.features, data.labels, seeds, key)
+                return params, EngineState(opt=opt, err=state.err,
+                                           guard=g), m
+            if seeds.shape[0] % self.num_parts:
+                raise ValueError(
+                    f"global seed batch {seeds.shape[0]} must divide over "
+                    f"{self.num_parts} devices (pad with pad_seeds)")
             if self.guard is None:
-                params, opt, m = self.step_fn(params, state.opt, data.graph,
-                                              data.features, data.labels,
-                                              seeds, key)
-                return params, EngineState(opt=opt, err=state.err), m
-            params, opt, g, m = self.step_fn(params, state.opt, state.guard,
-                                             data.graph, data.features,
-                                             data.labels, seeds, key)
-            return params, EngineState(opt=opt, err=state.err, guard=g), m
-        if seeds.shape[0] % self.num_parts:
-            raise ValueError(
-                f"global seed batch {seeds.shape[0]} must divide over "
-                f"{self.num_parts} devices (pad with pad_seeds)")
-        if self.guard is None:
-            params, opt, err, m = self.step_fn(params, state.opt, state.err,
-                                               data.indptr, data.indices,
-                                               data.features, data.labels,
-                                               seeds, key)
-            return params, EngineState(opt=opt, err=err), m
-        params, opt, err, g, m = self.step_fn(params, state.opt, state.err,
-                                              state.guard, data.indptr,
-                                              data.indices, data.features,
-                                              data.labels, seeds, key)
-        return params, EngineState(opt=opt, err=err, guard=g), m
+                params, opt, err, m = self.step_fn(
+                    params, state.opt, state.err, data.indptr, data.indices,
+                    data.features, data.labels, seeds, key)
+                return params, EngineState(opt=opt, err=err), m
+            params, opt, err, g, m = self.step_fn(
+                params, state.opt, state.err, state.guard, data.indptr,
+                data.indices, data.features, data.labels, seeds, key)
+            return params, EngineState(opt=opt, err=err, guard=g), m
 
     def _read_overflow(self, m):
         """The ONE place step metrics' overflow flags are read for the
@@ -1137,12 +1140,13 @@ class TrainEngine:
         """Double every static cap (LayerCaps + per-peer all-to-all) and
         invalidate the compiled steps — the logarithmic overflow-retry
         schedule."""
-        self.sampler = self.sampler.doubled()
-        self._step = None
-        self._infer = None
-        self._staged = None
-        self._infer_cached = {}
-        self.generation += 1
+        with jax.profiler.TraceAnnotation(spans.ENGINE_GROW):
+            self.sampler = self.sampler.doubled()
+            self._step = None
+            self._infer = None
+            self._staged = None
+            self._infer_cached = {}
+            self.generation += 1
 
     def step(self, params, state: EngineState, data: EngineData, seeds, key,
              tag: Any = None):
@@ -1151,22 +1155,29 @@ class TrainEngine:
         (free — its program has retired) and an overflowed batch is
         replayed with doubled caps. Returns (params, state, metrics) of
         THIS batch; replay metrics land in :attr:`replayed`."""
-        params, state, m = self._dispatch(params, state, data, seeds, key)
-        due = self._ledger.record((seeds, key, tag, self.sampler),
-                                  self._read_overflow(m))
-        if due is not None:
-            params, state, _ = self._replay(params, state, data, *due)
-        return params, state, m
+        with jax.profiler.TraceAnnotation(spans.ENGINE_STEP):
+            params, state, m = self._dispatch(params, state, data, seeds,
+                                              key)
+            flags = self._read_overflow(m)
+            with jax.profiler.TraceAnnotation(spans.ENGINE_POLL):
+                # blocks until the previous batch's program has retired
+                due = self._ledger.record((seeds, key, tag, self.sampler),
+                                          flags)
+            if due is not None:
+                params, state, _ = self._replay(params, state, data, *due)
+            return params, state, m
 
     def flush(self, params, state: EngineState, data: EngineData):
         """Resolve the last in-flight batch (end of training, or before
         persisting a checkpoint: a gated no-op batch must be replayed
         before its params are saved). Returns (params, state, metrics of
         the replayed batch or None)."""
-        due = self._ledger.flush()
-        if due is None:
-            return params, state, None
-        return self._replay(params, state, data, *due)
+        with jax.profiler.TraceAnnotation(spans.ENGINE_FLUSH):
+            with jax.profiler.TraceAnnotation(spans.ENGINE_POLL):
+                due = self._ledger.flush()
+            if due is None:
+                return params, state, None
+            return self._replay(params, state, data, *due)
 
     def _replay(self, params, state, data, seeds, key, tag, sampler_then):
         box = {"params": params, "state": state, "then": sampler_then}
@@ -1184,9 +1195,10 @@ class TrainEngine:
                 return None
             return (p, s, m)
 
-        return RetryPolicy(self.max_replay_retries).run(
-            attempt, error=SamplingOverflowError,
-            describe="sampling overflow persisted after cap doubling")
+        with jax.profiler.TraceAnnotation(spans.ENGINE_REPLAY):
+            return RetryPolicy(self.max_replay_retries).run(
+                attempt, error=SamplingOverflowError,
+                describe="sampling overflow persisted after cap doubling")
 
     def infer(self, params, data: EngineData, seeds, key):
         """Fused inference through the engine (see :attr:`infer_fn`)."""

@@ -123,3 +123,37 @@ def test_edge_softmax_compiles_for_v5e(compiled):
                    ((E_EDGE,), I32), ((E_EDGE,), BOOL),
                    ((E_EDGE, GAT_HEADS), F32))
     assert "tpu_custom_call" in hlo
+
+
+# kernel -> the op-path ending its Pallas call must carry on the chip:
+# the frontier sorts and the gather by their kernel names; the SpMM and
+# edge-softmax kernels directly under their jitted wrappers, the paths
+# the benchmark's roofline readers match
+_E, _S, _F = 4096, 256, 128
+KERNEL_PATHS = {
+    "frontier_sort": (
+        lambda v, m, s: pallas_backend.hash_dedup(v, m, s, 2 * _S),
+        "/frontier_sort_blocks/pallas_call",
+        ((_E,), I32), ((_E,), BOOL), ((_S,), I32)),
+    "gather_rows_sorted": (
+        gather_dst_block, "/gather_rows_sorted/pallas_call",
+        ((_E,), I32), ((_E,), BOOL), ((_S, _F), F32)),
+    "spmm_sorted": (
+        lambda s, d, w, m, h: spmm_block(s, d, w, m, h, _S),
+        "jit(spmm_sorted)/pallas_call",
+        ((_E,), I32), ((_E,), I32), ((_E,), F32), ((_E,), BOOL),
+        ((2 * _S, _F), F32)),
+    "edge_softmax_stats": (
+        lambda d, m, lg: edge_softmax_block(d, m, lg, _S),
+        "jit(edge_softmax_stats)/pallas_call",
+        ((_E,), I32), ((_E,), BOOL), ((_E, GAT_HEADS), F32)),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNEL_PATHS))
+def test_kernel_op_path_on_v5e(kernel, compiled):
+    from bench.trace import op_paths
+    fn, ending, *shapes = KERNEL_PATHS[kernel]
+    paths = op_paths(compiled(fn, *shapes)).values()
+    assert any(p.endswith(ending) for p in paths), sorted(
+        p for p in paths if p.endswith("pallas_call"))
