@@ -1,16 +1,22 @@
 """Grid-parallel Pallas kernels for the frontier primitives.
 
-Every primitive of the family reduces to ONE Pallas building block, a
-lane-dense bitonic sort of int32 word tuples (:func:`sort_words`), plus
-cap-sized XLA elementwise ops, scans and gathers around it (TPU
-gathers are fine; the data motion that needs a kernel is the sort):
+Every primitive of the family is built from two blocks: a lane-dense
+bitonic sort of int32 word tuples (:func:`sort_words`, in Pallas) and a
+segmented forward fill (:func:`fill_forward`), which carries each
+sorted run's head value over its run; cap-sized XLA elementwise ops and
+cumulative sums sit around them. Data-dependent gathers over cap-sized
+buffers cost about 20 ns an element on a TPU v5e (three over 2^24
+elements were a third of a products training step), so a value that
+has to reach the rest of its run travels by the fill, in streaming
+passes, not by a gather:
 
   * ``hash_dedup``     — sort ``[seeds ; values]`` by (value, position):
                          runs of equal values are adjacent, a seed sorts
                          first in its run, so run heads give the unique
                          new values and every run's slot in
-                         ``[seeds ; new]``; a second sort keyed by
-                         position puts the slots back in edge order.
+                         ``[seeds ; new]``, which the fill carries over
+                         the run; a second sort keyed by position puts
+                         the slots back in edge order.
   * ``compact``        — sort flag-tagged positions (set flags first, in
                          arrival order) and keep the head.
   * ``compact_perm``   — sort (key, index) — packed into one word when
@@ -255,6 +261,23 @@ def _exclusive_cumsum(b):
     return jnp.cumsum(b) - b
 
 
+def fill_forward(head, x):
+    """Each position takes the value of the nearest head at or before
+    it; position 0 is a head."""
+    # a doubling scan, log2(n) streaming shift-and-select passes:
+    # jax.lax.associative_scan's strided recursion takes the TPU
+    # compiler minutes from n = 2**18 on
+    n = x.shape[0]
+    d = 1
+    while d < n:
+        # combine each position with the one d before it:
+        # (fa | fb, where(fb, vb, va)), a = position - d, b = position
+        x = jnp.where(head, x, jnp.pad(x[:-d], (d, 0)))
+        head = head | jnp.pad(head[:-d], (d, 0))
+        d *= 2
+    return x
+
+
 # ---------------------------------------------------------------------------
 # hash_dedup
 # ---------------------------------------------------------------------------
@@ -274,11 +297,12 @@ def _dedup(values, mask, seeds, new_cap: int, tile: int, interpret: bool):
     seed_at = pos < s
     new_head = head & live & ~seed_at
     num_new = jnp.sum(new_head.astype(jnp.int32))
+    # rank only steps at a new run's head, so it is constant over runs;
+    # the slot is right at every head and filled forward over its run
     rank = jnp.cumsum(new_head.astype(jnp.int32)) - 1
-    run = jax.lax.cummax(jnp.where(head, _iota(n), 0))
-    slot = jnp.where(seed_at[run], pos[run],
-                     jnp.where(rank[run] < new_cap, s + rank[run], -1))
-    slot = jnp.where(live, slot, -1)
+    at_head = jnp.where(seed_at, pos,
+                        jnp.where(rank < new_cap, s + rank, -1))
+    slot = jnp.where(live, fill_forward(head, at_head), -1)
     (newv,) = sort_words([jnp.where(new_head, v, _INT_MAX)], 1, tile,
                          interpret)
     new = jnp.where(_iota(new_cap) < num_new, _head(newv, new_cap, -1), -1)

@@ -17,6 +17,8 @@ Four layers of checks:
     every registry sampler's ``sample`` jaxpr asserting NO intermediate
     buffer is sized by the vertex count (caps only).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -437,19 +439,25 @@ def test_ladies_candidate_path_matches_dense(ds, poisson):
 # the acceptance criterion: no V-sized intermediates in any sample trace
 # ---------------------------------------------------------------------------
 
-def _collect_avals(jaxpr, out):
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
     for eqn in jaxpr.eqns:
-        for v in eqn.outvars:
-            aval = getattr(v, "aval", None)
-            if aval is not None and hasattr(aval, "shape"):
-                out.append(aval)
+        yield eqn
         for val in eqn.params.values():
             vals = val if isinstance(val, (tuple, list)) else (val,)
             for x in vals:
                 if hasattr(x, "jaxpr"):        # ClosedJaxpr
-                    _collect_avals(x.jaxpr, out)
+                    yield from _eqns(x.jaxpr)
                 elif hasattr(x, "eqns"):       # Jaxpr
-                    _collect_avals(x, out)
+                    yield from _eqns(x)
+
+
+def _collect_avals(jaxpr, out):
+    for eqn in _eqns(jaxpr):
+        for v in eqn.outvars:
+            aval = getattr(v, "aval", None)
+            if aval is not None and hasattr(aval, "shape"):
+                out.append(aval)
 
 
 @pytest.mark.parametrize("name", ["ns", "labor-0", "labor-1", "labor-*",
@@ -500,6 +508,24 @@ def test_dense_baseline_does_have_vertex_sized_intermediates(ds):
     avals = []
     _collect_avals(closed.jaxpr, avals)
     assert any(any(d == V for d in a.shape) for a in avals)
+
+
+def test_dedup_trace_has_no_buffer_sized_gathers():
+    """The sorted buffer's run heads reach the rest of their runs by a
+    forward fill, not by gathers: at a layer-2-like shape no gather in
+    the (nested) jaxpr of ``_dedup`` yields a buffer-sized output."""
+    S, E = 2**15, 2**18
+    n = frontier_par._pow2_at_least(S + E)
+    closed = jax.make_jaxpr(functools.partial(
+        frontier_par._dedup, new_cap=S, tile=1024, interpret=True))(
+        jax.ShapeDtypeStruct((E,), jnp.int32),
+        jax.ShapeDtypeStruct((E,), jnp.bool_),
+        jax.ShapeDtypeStruct((S,), jnp.int32))
+    eqns = list(_eqns(closed.jaxpr))
+    assert any(e.primitive.name == "pallas_call" for e in eqns)
+    bad = [e for e in eqns if e.primitive.name == "gather"
+           and any(n in getattr(v.aval, "shape", ()) for v in e.outvars)]
+    assert not bad, [v.aval for e in bad for v in e.outvars]
 
 
 # ---------------------------------------------------------------------------
@@ -588,6 +614,25 @@ def test_sort_words_matches_lexsort(n, tile, n_keys, n_words):
         np.testing.assert_array_equal(o, k[order])
     assert sorted(zip(*[w.tolist() for w in keys + pays])) == \
         sorted(zip(*[o.tolist() for o in out]))
+
+
+@pytest.mark.parametrize("n,density", [
+    (8, 1.0), (8, 0.0), (8, 0.5), (100, 0.01), (1000, 0.3), (4096, 0.9),
+    (2**16, 0.0), (2**16, 0.01), (2**16, 0.2), (2**16, 1.0)])
+def test_fill_forward_matches_run_head_gather(n, density):
+    """``fill_forward`` equals the gather it replaces, ``x[run]`` with
+    ``run`` the cummax of head positions, whatever the heads' density,
+    for negative values and values near the int32 top."""
+    rng = np.random.default_rng(n * 7 + int(density * 100))
+    head = rng.random(n) < density
+    head[0] = True
+    x = np.where(rng.random(n) < 0.5,
+                 rng.integers(-2**31, 0, n),
+                 rng.integers(2**31 - 1000, 2**31, n)).astype(np.int32)
+    run = np.maximum.accumulate(np.where(head, np.arange(n), 0))
+    got = jax.jit(frontier_par.fill_forward)(jnp.asarray(head),
+                                             jnp.asarray(x))
+    np.testing.assert_array_equal(np.asarray(got), x[run])
 
 
 @settings(max_examples=15, deadline=None)
