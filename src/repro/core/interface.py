@@ -25,11 +25,13 @@ serving.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import rng as rng_lib
 from repro.core.cs_solve import _segment_sum
@@ -105,15 +107,92 @@ class LayerCaps:
 
 
 def double_caps(caps: Sequence[LayerCaps]) -> list[LayerCaps]:
-    """The overflow-retry schedule: double every buffer of every layer.
-
-    One jit specialization exists per cap schedule, so doubling (rather
-    than fitting exactly) keeps the number of recompiles logarithmic.
-    Samplers carrying distributed per-peer all-to-all caps should be
-    grown with :meth:`Sampler.doubled`, which doubles those too."""
+    """Every buffer of every layer doubled: what :func:`grow_caps` does
+    where no buffer was measured past its cap. That is a mesh's step,
+    which reports no per-layer counts, or an overflow flag that the
+    counts do not explain (an injected storm)."""
     return [dataclasses.replace(c, expand_cap=c.expand_cap * 2,
                                 edge_cap=c.edge_cap * 2,
                                 vertex_cap=c.vertex_cap * 2) for c in caps]
+
+
+# the buffers of one layer that an overflow can name
+BUFFERS = ("expand", "edge", "vertex")
+
+
+def _vertex_layout(caps: Sequence[LayerCaps], seed_cap: int) -> list:
+    """The one statement of the vertex buffers' layout: each layer's
+    vertex buffer is ``[seed buffer ; room for new vertices]``, its seed
+    buffer the batch's (``seed_cap`` long) at layer 0 and the previous
+    layer's vertex buffer below. Returns ``(seed buffer, room)`` per
+    layer."""
+    bufs = [seed_cap] + [c.vertex_cap for c in caps[:-1]]
+    return [(b, c.vertex_cap - b) for b, c in zip(bufs, caps)]
+
+
+def cap_overflows(caps: Sequence[LayerCaps], layer_counts,
+                  seeds) -> Optional[dict]:
+    """The buffers that a batch's measured counts show past their caps:
+    ``{(layer, buffer): (size, need)}``, or None where nothing was
+    measured past its cap (``layer_counts`` None, or a flag the counts
+    do not explain).
+
+    ``layer_counts`` is the step's int32[L, 3] (:func:`sampled_counts`)
+    and ``seeds`` the padded seed batch it was sampled from. The
+    expanded count is exact. The sampled count is exact unless the
+    expand buffer overflowed, and the next count unless the edge buffer
+    did: then each counts what fitted, a lower bound, and a replay that
+    overflows again is grown again from its own counts. A vertex
+    buffer's size here is its room for new vertices
+    (:func:`_vertex_layout`), and its need the new vertices, ``next``
+    less the real seeds."""
+    if layer_counts is None:
+        return None
+    counts = np.asarray(layer_counts)
+    seeds = np.asarray(seeds)
+    n_seeds = int(np.sum(seeds >= 0))
+    over = {}
+    for l, (c, (_, room), (expanded, sampled, nxt)) in enumerate(
+            zip(caps, _vertex_layout(caps, seeds.shape[0]), counts)):
+        for buf, size, need in (("expand", c.expand_cap, expanded),
+                                ("edge", c.edge_cap, sampled),
+                                ("vertex", room, nxt - n_seeds)):
+            if need > size:
+                over[(l, buf)] = (int(size), int(need))
+        # the next layer's seeds: this layer's, and the new vertices
+        # that fitted
+        n_seeds += min(int(nxt) - n_seeds, room)
+    return over or None
+
+
+def grow_caps(caps: Sequence[LayerCaps], over: Optional[dict],
+              seed_cap: Optional[int]) -> list[LayerCaps]:
+    """The overflow-retry growth rule: each buffer in ``over``
+    (:func:`cap_overflows`) grows to max(2 x size, 1.25 x need), rounded
+    up to 128; every other buffer keeps its size, and a vertex buffer
+    keeps its room for new vertices when the seed buffer beneath it
+    grows. One jit specialization exists per cap schedule, and the
+    doubling floor keeps the number of recompiles logarithmic. With
+    ``over`` None every buffer doubles (:func:`double_caps`).
+    ``seed_cap`` is the length of the batch's seed buffer, read only
+    where ``over`` names a buffer."""
+    if over is None:
+        return double_caps(caps)
+
+    def size(l, buf, old):
+        if (l, buf) not in over:
+            return old
+        cap, need = over[(l, buf)]
+        return _round_up(max(2 * cap, math.ceil(1.25 * need)), 128)
+
+    out = []
+    for l, (c, (_, room)) in enumerate(zip(caps,
+                                           _vertex_layout(caps, seed_cap))):
+        seed_buf = out[-1].vertex_cap if out else seed_cap
+        out.append(LayerCaps(expand_cap=size(l, "expand", c.expand_cap),
+                             edge_cap=size(l, "edge", c.edge_cap),
+                             vertex_cap=seed_buf + size(l, "vertex", room)))
+    return out
 
 
 def suggest_peer_caps(batch_size: int, caps: Sequence[LayerCaps],
@@ -149,7 +228,8 @@ def suggest_caps(
 
     Poisson sampling concentrates tightly around its mean (sum of
     independent Bernoullis), so mean * safety + a few sigma is enough;
-    the pipeline retries with doubled caps on detected overflow. Caps are
+    an overflow is replayed with the buffers it names grown
+    (:func:`grow_caps`). Caps are
     clamped to the whole graph when ``num_vertices``/``num_edges`` given.
     """
     caps = []
@@ -201,16 +281,16 @@ class SamplerSpec:
                 ladies family, a cap-sizing hint for ``full``.
       caps:     static buffer schedule, one :class:`LayerCaps` per layer.
                 Caps live HERE (not on sampler configs): overflow retry
-                is ``sampler.with_caps(double_caps(sampler.caps))``.
+                is :meth:`grown`.
       shared_salts: one salt reused across layers (§A.8 layer
                 dependency) instead of an independent salt per layer.
       peer_caps: optional per-peer all-to-all slot schedule for the
                 partition-aware distributed engine (length num_layers+1,
                 see :func:`suggest_peer_caps`); ``None`` on samplers
                 built without a partition count. Overflow replay doubles
-                them alongside the LayerCaps (:meth:`Sampler.doubled`),
-                so a feature-exchange overflow heals through the same
-                doubled-caps protocol as a sampling overflow.
+                them alongside the LayerCaps (:meth:`grown`), so a
+                feature-exchange overflow heals through the same
+                protocol as a sampling overflow.
     """
     name: str
     budgets: tuple
@@ -251,16 +331,19 @@ class SamplerSpec:
 
     def with_caps(self, caps: Sequence[LayerCaps]) -> "SamplerSpec":
         """New LayerCaps schedule; ``peer_caps`` are left untouched (use
-        :meth:`doubled` for the overflow-retry growth of both)."""
+        :meth:`grown` for the overflow-retry growth of both)."""
         return dataclasses.replace(self, caps=tuple(caps))
 
-    def doubled(self) -> "SamplerSpec":
-        """The overflow-retry step: every LayerCaps buffer and every
-        per-peer all-to-all cap doubled."""
+    def grown(self, over: Optional[dict],
+              seed_cap: Optional[int]) -> "SamplerSpec":
+        """The overflow-retry step: the LayerCaps buffers ``over`` names
+        grown by :func:`grow_caps`, every per-peer all-to-all cap
+        doubled."""
         peer = (None if self.peer_caps is None
                 else tuple(c * 2 for c in self.peer_caps))
-        return dataclasses.replace(self, caps=tuple(double_caps(self.caps)),
-                                   peer_caps=peer)
+        return dataclasses.replace(
+            self, caps=tuple(grow_caps(self.caps, over, seed_cap)),
+            peer_caps=peer)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -300,12 +383,13 @@ class Sampler:
         """Clone with a new static cap schedule (same sampling math)."""
         return dataclasses.replace(self, spec=self.spec.with_caps(caps))
 
-    def doubled(self) -> "Sampler":
-        """The one overflow-retry idiom: LayerCaps AND per-peer
-        all-to-all caps doubled, sampling math unchanged. Single-host
-        call sites that predate peer caps (`with_caps(double_caps(...))`)
-        remain equivalent when ``spec.peer_caps is None``."""
-        return dataclasses.replace(self, spec=self.spec.doubled())
+    def grown(self, over: Optional[dict],
+              seed_cap: Optional[int]) -> "Sampler":
+        """The one overflow-retry idiom (:meth:`SamplerSpec.grown`):
+        the overflowed LayerCaps buffers grown, per-peer all-to-all caps
+        doubled, sampling math unchanged."""
+        return dataclasses.replace(self, spec=self.spec.grown(over,
+                                                              seed_cap))
 
     def sample_layer_partitioned(self, graph, seeds: jax.Array,
                                  salt: jax.Array, layer: int, *,

@@ -271,37 +271,41 @@ def sample_layer(
     src, slot, mask, deg = exp["src"], exp["seed_slot"], exp["mask"], exp["deg"]
     safe_slot = jnp.clip(slot, 0, S - 1)
 
-    if graph.weights is None:
-        pi_e, c = run_importance_iterations(
-            graph, exp, k, S, importance_iters, converge_tol,
-            converge_max_iters, fast_solve=fast_solve,
-            num_vertices=num_vertices, axis_name=axis_name,
-        )
-    else:
-        # weighted case (§A.7): per-edge pi initialised to A_ts
-        a_e = exp["edge_weight"]
-        pi_e = jnp.where(mask, a_e, 1.0)
-        c = solve_cs_weighted(pi_e, a_e, slot, deg, k, S, mask)
+    # from the expanded edges to the inclusion mask and 1/p: the
+    # importance iterations, the variates, c_s per edge, the comparison
+    # or the exact-k selection
+    with jax.named_scope(spans.INCLUDE):
+        if graph.weights is None:
+            pi_e, c = run_importance_iterations(
+                graph, exp, k, S, importance_iters, converge_tol,
+                converge_max_iters, fast_solve=fast_solve,
+                num_vertices=num_vertices, axis_name=axis_name,
+            )
+        else:
+            # weighted case (§A.7): per-edge pi initialised to A_ts
+            a_e = exp["edge_weight"]
+            pi_e = jnp.where(mask, a_e, 1.0)
+            c = solve_cs_weighted(pi_e, a_e, slot, deg, k, S, mask)
 
-    # Inclusion: r < c_s * pi_t with shared-per-vertex r (LABOR) or
-    # per-edge r (NS equivalence).
-    if per_edge_rng:
-        r = rng_lib.hash_uniform_edge(salt, src, jnp.where(mask, seeds[safe_slot], 0))
-    else:
-        r = rng_lib.hash_uniform(salt, src)
-    c_e = c[safe_slot]
-    prob = jnp.minimum(1.0, c_e * jnp.maximum(pi_e, 0.0))
+        # Inclusion: r < c_s * pi_t with shared-per-vertex r (LABOR) or
+        # per-edge r (NS equivalence).
+        if per_edge_rng:
+            r = rng_lib.hash_uniform_edge(salt, src, jnp.where(mask, seeds[safe_slot], 0))
+        else:
+            r = rng_lib.hash_uniform(salt, src)
+        c_e = c[safe_slot]
+        prob = jnp.minimum(1.0, c_e * jnp.maximum(pi_e, 0.0))
 
-    if exact_k:
-        ratio = jnp.where(mask, r / jnp.maximum(c_e * pi_e, 1e-20), 3.4e38)
-        include = _exact_k_include(ratio, slot, mask, deg, exp["seg_start"], k, S, caps.expand_cap)
-    else:
-        include = mask & (r < c_e * pi_e)
+        if exact_k:
+            ratio = jnp.where(mask, r / jnp.maximum(c_e * pi_e, 1e-20), 3.4e38)
+            include = _exact_k_include(ratio, slot, mask, deg, exp["seg_start"], k, S, caps.expand_cap)
+        else:
+            include = mask & (r < c_e * pi_e)
+        inv_p = 1.0 / jnp.maximum(prob, 1e-20)
 
     # Hajek normalization + edge compaction + next_seeds construction is
     # the epilogue every sampler shares (core.interface.build_block).
-    return build_block(seeds, exp, include,
-                       1.0 / jnp.maximum(prob, 1e-20), caps)
+    return build_block(seeds, exp, include, inv_p, caps)
 
 
 def layer_salts(cfg: LaborConfig, key: jax.Array) -> jax.Array:

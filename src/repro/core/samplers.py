@@ -208,12 +208,12 @@ def from_graph_stats(name: str, *, batch_size: int, fanouts: Sequence[int],
     static buffers from fanout geometry (full-neighborhood geometry for
     ``dense`` entries like ``full``), the ladies family takes
     ``layer_sizes`` as budgets (default ``batch_size * k`` per layer),
-    and overflow retry later goes through ``Sampler.doubled``.
+    and overflow retry later goes through ``Sampler.grown``.
 
     ``num_parts`` sizes the distributed engine's per-peer all-to-all
     caps (``spec.peer_caps``, see :func:`suggest_peer_caps`) alongside
     the LayerCaps, with ``batch_size`` read as the DEVICE-LOCAL seed
-    batch; overflow replay then doubles both schedules together.
+    batch; overflow replay then grows both schedules together.
     """
     entry = resolve(name)
     fanouts = tuple(int(k) for k in fanouts)

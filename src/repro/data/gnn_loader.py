@@ -3,8 +3,9 @@ management with overflow retry, and straggler mitigation.
 
 The sampler itself is device-side (repro.core); this pipeline feeds it
 padded seed batches and watches the ``overflow`` flags it returns. On
-overflow the batch is retried with doubled caps (new jit specialization —
-rare, amortized). A watchdog timestamps batch production; batches slower
+overflow the batch is retried with the overflowed buffers grown
+(``repro.core.interface.grow_caps``; a new jit specialization — rare,
+amortized). A watchdog timestamps batch production; batches slower
 than ``straggler_timeout`` (e.g. a slow storage shard on a real cluster)
 are *skipped* and counted, which keeps the synchronous optimizer step
 from stalling the whole pod — the standard bounded-staleness mitigation.
@@ -22,7 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.interface import double_caps, pad_seeds
+from repro.core.interface import (BUFFERS, cap_overflows, pad_seeds,
+                                  sampled_counts)
 from repro.runtime.guard import RetryPolicy
 
 
@@ -38,11 +40,23 @@ class LoaderStats:
     # pipelined path: in-flight batches re-sampled after a replay grew
     # the cap schedule (runtime/pipeline.py)
     pipeline_invalidations: int = 0
+    # {(layer, buffer): growths} of the LayerCaps buffers, buffer one of
+    # repro.core.interface.BUFFERS; a growth that measured nothing
+    # (every buffer doubled) counts for every buffer
+    growths: dict = dataclasses.field(default_factory=dict)
+
+    def record_growth(self, over, num_layers: int) -> None:
+        """Count one growth of the buffers ``over`` names
+        (:func:`repro.core.interface.cap_overflows`; None: all)."""
+        keys = (over.keys() if over is not None else
+                [(l, b) for l in range(num_layers) for b in BUFFERS])
+        for k in keys:
+            self.growths[k] = self.growths.get(k, 0) + 1
 
 
 class SamplingOverflowError(RuntimeError):
     """Sampling (or all-to-all) overflow persisted after the cap-
-    doubling retry schedule was exhausted.
+    growth retry schedule was exhausted.
 
     The ONE error type every overflow-retry surface raises — the eager
     :func:`sample_with_retry`, the engine's async replay protocol
@@ -153,11 +167,12 @@ class PrefetchIterator:
 
 def sample_with_retry(sampler, graph, seeds, key,
                       stats: Optional[LoaderStats] = None, max_retries: int = 3):
-    """Run a :class:`~repro.core.interface.Sampler`; on overflow double
-    its cap schedule (``sampler.with_caps``) and retry (one jit
-    specialization per cap schedule). Returns ``(blocks, sampler)`` where
-    the returned sampler carries the possibly-doubled caps — callers
-    thread it forward so later batches start from the grown schedule.
+    """Run a :class:`~repro.core.interface.Sampler`; on overflow grow
+    the buffers the blocks' counts show past their caps
+    (``sampler.grown``) and retry (one jit specialization per cap
+    schedule). Returns ``(blocks, sampler)`` where the returned sampler
+    carries the possibly-grown caps — callers thread it forward so
+    later batches start from the grown schedule.
 
     This is the *eager* protocol: it forces a device->host sync on every
     batch to read the overflow flags before the optimizer step may run.
@@ -168,18 +183,21 @@ def sample_with_retry(sampler, graph, seeds, key,
     def attempt(_i):
         blocks = box["sampler"].sample_with_key(graph, seeds, key)
         if any(bool(b.overflow) for b in blocks):
+            box["counts"] = sampled_counts(blocks)["layer_counts"]
             return None
         return blocks
 
     def grow(_i):
+        s = box["sampler"]
+        over = cap_overflows(s.caps, box["counts"], seeds)
         if stats is not None:
             stats.overflow_retries += 1
-        box["sampler"] = box["sampler"].with_caps(
-            double_caps(box["sampler"].caps))
+            stats.record_growth(over, s.num_layers)
+        box["sampler"] = s.grown(over, seeds.shape[0])
 
     blocks = RetryPolicy(max_retries).run(
         attempt, grow=grow, error=SamplingOverflowError,
-        describe="sampling overflow persisted after cap doubling")
+        describe="sampling overflow persisted after cap growth")
     return blocks, box["sampler"]
 
 
@@ -195,7 +213,12 @@ class OverflowLedger:
     The ledger is owned by :class:`repro.runtime.engine.TrainEngine`,
     which records each batch here, polls the flags one step late — by
     then the program has retired, so reading the scalar costs nothing —
-    and replays the skipped batch with doubled caps. On a mesh the
+    and replays the skipped batch with the overflowed buffers grown
+    from the batch's own ``layer_counts``, which the recorded tag
+    carries. The first batch under a cap schedule that has never run
+    is the exception: the engine polls it at once (:meth:`flush`), so
+    a schedule too small for the graph is found before its first
+    update is returned, and every later poll is one step late. On a mesh the
     polled flag vector also carries the distributed step's all-to-all
     overflow (seed routing, feature/hidden exchange), so one protocol
     heals every static cap in the program.

@@ -58,8 +58,8 @@ def _build_gnn_serving(args):
                                       {"params": params})["params"]
 
     # the engine's fused infer program from the same registry object +
-    # overflow protocol as training: engine.grow() doubles every cap
-    # and rebuilds (rare, amortized)
+    # overflow protocol as training: engine.grow() grows the caps that
+    # overflowed and rebuilds (rare, amortized)
     sampler = samplers.from_dataset(args.sampler, ds, batch_size=args.batch,
                                     fanouts=fanouts, safety=2.0)
     engine = TrainEngine(sampler, apply_fn, adam.AdamConfig(),

@@ -53,9 +53,17 @@ def gcn_layer(p, blk: SampledLayer, h: jax.Array, *, is_last: bool,
     """One GCN layer over one sampled block: h over ``blk.next_seeds``
     in, h over ``blk.seeds`` out. The per-layer granularity is what the
     distributed engine interleaves with cross-partition hidden-state
-    exchanges; the whole-batch ``gcn_apply`` chains the same function."""
-    agg = O.aggregate(blk, h, backend=backend)                # (S, F_in)
-    z = agg @ p["w"] + p["b"]
+    exchanges; the whole-batch ``gcn_apply`` chains the same function.
+
+    The aggregation runs at the narrower of the two widths, as DGL's
+    GraphConv does: (A h) W = A (h W), so a layer that narrows projects
+    first. At 602 input features, aggregating first would write the
+    block's edges x 602 message tensors (26.9 GB for a Reddit-sized
+    graph's deepest block at batch 1024)."""
+    if h.shape[-1] > p["w"].shape[-1]:
+        z = O.aggregate(blk, h @ p["w"], backend=backend) + p["b"]
+    else:
+        z = O.aggregate(blk, h, backend=backend) @ p["w"] + p["b"]
     res = h[: blk.seed_cap] @ p["wr"]                          # seeds prefix
     h = z + res
     return h if is_last else jax.nn.relu(h)

@@ -43,7 +43,8 @@ Constructed from ``(sampler, model_apply, optimizer, mesh | None)``:
   hidden exchange) feeds the same stacked flag vector, so one protocol
   covers them all: the update is gated on device, the engine-owned
   ledger polls the flags one step late, and the batch is replayed with
-  ``Sampler.doubled`` caps.
+  ``Sampler.grown`` caps (on a mesh, whose step reports no per-layer
+  counts, every cap doubles).
 
 The paper connection: LABOR's ~7x reduction in sampled vertices
 (Table 2) multiplies directly into the bytes of every one of these
@@ -62,7 +63,8 @@ from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P_
 
 from repro import ops as graph_ops
-from repro.core.interface import Sampler, overflow_flags, sampled_counts
+from repro.core.interface import (Sampler, cap_overflows, overflow_flags,
+                                  sampled_counts)
 from repro.data.gnn_loader import (LoaderStats, OverflowLedger,
                                    SamplingOverflowError)
 from repro.runtime import spans
@@ -323,10 +325,11 @@ class TrainEngine:
     ``step`` owns the async overflow protocol end to end: it dispatches
     the fused program, records the device-resident overflow flags in the
     engine's ledger, polls the PREVIOUS batch's flags (already retired —
-    free), and replays an overflowed batch with ``Sampler.doubled`` caps
-    — sampling-cap and all-to-all-cap overflow alike. Replay metrics
-    are appended to :attr:`replayed` as ``(tag, metrics)`` for callers
-    that keep step-indexed histories.
+    free), and replays an overflowed batch with ``Sampler.grown`` caps
+    — sampling-cap and all-to-all-cap overflow alike. The first batch
+    under a cap schedule that has never run is polled before ``step``
+    returns. Replay metrics are appended to :attr:`replayed` as
+    ``(tag, metrics)`` for callers that keep step-indexed histories.
 
     On a mesh the sampler must carry ``spec.peer_caps`` (build it with
     ``samplers.from_graph_stats(..., num_parts=P)`` and the DEVICE-LOCAL
@@ -374,6 +377,9 @@ class TrainEngine:
         self.stats = stats or LoaderStats()
         self.replayed: List[Tuple[Any, Dict[str, Any]]] = []
         self._ledger = OverflowLedger(self.stats)
+        # the sampler (cap schedule) a batch has run under without
+        # overflow; a batch under any other is polled at once (_settle)
+        self._proven: Optional[Sampler] = None
         self._step = None
         self._infer = None
         self._staged = None
@@ -484,8 +490,10 @@ class TrainEngine:
         """Fused sample + gather + forward, from the same sampler object.
 
         Single-host: ``infer(params, graph, features, seeds, key) ->
-        (logits, overflow_flags)`` — exact with the ``full`` registry
-        entry, sampled otherwise. Distributed: ``infer(params, indptr,
+        (logits, overflow_flags, layer_counts)`` — exact with the
+        ``full`` registry entry, sampled otherwise; ``layer_counts``
+        (int32[L, 3], :func:`sampled_counts`) size a :meth:`grow` after
+        an overflow. Distributed: ``infer(params, indptr,
         indices, features, seeds, key) -> (owned_seeds, logits, flags)``
         where row i of ``logits`` answers global vertex
         ``owned_seeds[i]`` (each device returns its owned share of the
@@ -582,7 +590,8 @@ class TrainEngine:
             blocks = sampler.sample(graph, seeds, sampler.spec.salts(key))
             feats = gather_feats(features, blocks[-1])
             logits = apply_fn(params, blocks, feats, backend=backend)
-            return logits, overflow_flags(blocks)
+            return (logits, overflow_flags(blocks),
+                    sampled_counts(blocks)["layer_counts"])
 
         return infer
 
@@ -600,7 +609,7 @@ class TrainEngine:
         Signature::
 
             infer_c(params, graph, features, fc_state, hc_state,
-                    seeds, key) -> (logits, overflow_flags,
+                    seeds, key) -> (logits, overflow_flags, layer_counts,
                                     fc_state', hc_state', cache_metrics)
 
         Pass ``None`` for a disabled cache's state. Feature-cache
@@ -657,7 +666,8 @@ class TrainEngine:
                             hc_state, blk.seeds, h)
                         metrics.update(hm)
                 logits, hc_state_out = h, hc_state
-            return (logits, overflow_flags(blocks), fc_state_out,
+            return (logits, overflow_flags(blocks),
+                    sampled_counts(blocks)["layer_counts"], fc_state_out,
                     hc_state_out, metrics)
 
         self._infer_cached[cache_key] = infer_c
@@ -1136,12 +1146,21 @@ class TrainEngine:
         path: pending entries describe a discarded trajectory)."""
         self._ledger = OverflowLedger(self.stats, depth=self._ledger.depth)
 
-    def grow(self):
-        """Double every static cap (LayerCaps + per-peer all-to-all) and
-        invalidate the compiled steps — the logarithmic overflow-retry
-        schedule."""
+    def grow(self, layer_counts=None, seeds=None):
+        """Grow the cap schedule after an overflow and invalidate the
+        compiled programs. ``layer_counts`` (int32[L, 3]) are the counts
+        the overflowed batch measured and ``seeds`` its padded seed
+        batch: each buffer they show past its cap grows to
+        max(2 x cap, 1.25 x need), and no other
+        (:func:`~repro.core.interface.grow_caps`). Without counts (a
+        mesh's step reports none), or where they show no buffer past
+        its cap, every cap doubles; per-peer all-to-all caps always
+        double."""
         with jax.profiler.TraceAnnotation(spans.ENGINE_GROW):
-            self.sampler = self.sampler.doubled()
+            over = cap_overflows(self.sampler.caps, layer_counts, seeds)
+            self.stats.record_growth(over, self.sampler.num_layers)
+            self.sampler = self.sampler.grown(
+                over, None if over is None else seeds.shape[0])
             self._step = None
             self._infer = None
             self._staged = None
@@ -1153,19 +1172,44 @@ class TrainEngine:
         """One fused train step with the async overflow protocol: the
         update is gated on device; the PREVIOUS batch's flags are polled
         (free — its program has retired) and an overflowed batch is
-        replayed with doubled caps. Returns (params, state, metrics) of
-        THIS batch; replay metrics land in :attr:`replayed`."""
+        replayed with grown caps. Returns (params, state, metrics) of
+        THIS batch; replay metrics land in :attr:`replayed`.
+
+        The first batch under a cap schedule that has never run (after
+        construction, or after a :meth:`grow` from outside a replay) is
+        polled before ``step`` returns, and if it overflowed it is
+        replayed at once: ``step`` then returns the replayed batch's
+        params, state and metrics. That costs one host sync per new
+        schedule, beside a compile; every later batch is polled one
+        step late."""
         with jax.profiler.TraceAnnotation(spans.ENGINE_STEP):
             params, state, m = self._dispatch(params, state, data, seeds,
                                               key)
-            flags = self._read_overflow(m)
+            return self._settle(self._ledger, params, state, data,
+                                (seeds, key, tag, self.sampler), m)
+
+    def _settle(self, ledger: OverflowLedger, params, state, data, entry, m):
+        """Record a dispatched batch (``entry`` = seeds, key, tag,
+        sampler) in ``ledger`` with its flags and counts; replay what
+        the poll finds due, and poll the batch itself at once if its
+        schedule has never run. Shared by :meth:`step` and the
+        pipelined driver (``runtime/pipeline.py``), so both apply
+        replays in the same order."""
+        flags = self._read_overflow(m)
+        entry = (*entry, m.get("layer_counts"))
+        with jax.profiler.TraceAnnotation(spans.ENGINE_POLL):
+            # blocks until the previous batch's program has retired
+            due = ledger.record(entry, flags)
+        if due is not None:
+            params, state, _ = self._replay(params, state, data, *due)
+        if entry[3] is not self._proven:
             with jax.profiler.TraceAnnotation(spans.ENGINE_POLL):
-                # blocks until the previous batch's program has retired
-                due = self._ledger.record((seeds, key, tag, self.sampler),
-                                          flags)
-            if due is not None:
-                params, state, _ = self._replay(params, state, data, *due)
-            return params, state, m
+                due = ledger.flush()
+            if due is None:
+                self._proven = entry[3]
+            else:
+                params, state, m = self._replay(params, state, data, *due)
+        return params, state, m
 
     def flush(self, params, state: EngineState, data: EngineData):
         """Resolve the last in-flight batch (end of training, or before
@@ -1179,26 +1223,30 @@ class TrainEngine:
                 return params, state, None
             return self._replay(params, state, data, *due)
 
-    def _replay(self, params, state, data, seeds, key, tag, sampler_then):
-        box = {"params": params, "state": state, "then": sampler_then}
+    def _replay(self, params, state, data, seeds, key, tag, sampler_then,
+                counts):
+        box = {"params": params, "state": state, "then": sampler_then,
+               "counts": counts}
 
         def attempt(_i):
             if self.sampler is box["then"]:
                 self.stats.overflow_retries += 1
-                self.grow()
+                self.grow(box["counts"], seeds)
             p, s, m = self._dispatch(box["params"], box["state"], data,
                                      seeds, key)
             box["params"], box["state"] = p, s
             self.replayed.append((tag, m))
             if bool(jnp.any(self._read_overflow(m))):
-                box["then"] = self.sampler
+                box["then"], box["counts"] = self.sampler, m.get(
+                    "layer_counts")
                 return None
+            self._proven = self.sampler
             return (p, s, m)
 
         with jax.profiler.TraceAnnotation(spans.ENGINE_REPLAY):
             return RetryPolicy(self.max_replay_retries).run(
                 attempt, error=SamplingOverflowError,
-                describe="sampling overflow persisted after cap doubling")
+                describe="sampling overflow persisted after cap growth")
 
     def infer(self, params, data: EngineData, seeds, key):
         """Fused inference through the engine (see :attr:`infer_fn`)."""
@@ -1211,36 +1259,40 @@ class TrainEngine:
     def infer_with_retry(self, params, data: EngineData, seeds, key, *,
                          max_retries: int = 4):
         """:meth:`infer` under the trainer's overflow-retry contract:
-        on overflow, :meth:`grow` (doubled caps, fresh specialization)
-        and re-run with the SAME key — the sampled set is
-        salt-determined, so the retry answers the same request, just
-        un-truncated. Raises
+        on overflow, :meth:`grow` from the batch's ``layer_counts`` (a
+        fresh specialization) and re-run with the SAME key — the
+        sampled set is salt-determined, so the retry answers the same
+        request, just un-truncated. Raises
         :class:`~repro.data.gnn_loader.SamplingOverflowError` (the
         same type ``sample_with_retry`` and the async replay raise)
-        when ``max_retries`` doublings don't clear it, so serving
+        when ``max_retries`` growths don't clear it, so serving
         drivers catch cap exhaustion uniformly with training drivers.
 
         Returns ``(logits, grows)`` — ``grows`` > 0 tells the caller
         the dispatch paid one or more fresh compiles (latency
         accounting must tag, not fold, that time)."""
-        grows = {"n": 0}
+        box = {"grows": 0, "counts": None}
 
         def attempt(_i):
             out = self.infer(params, data, seeds, key)
-            if bool(jnp.any(out[-1])):    # overflow flags, both paths
+            # single-host (logits, flags, counts); a mesh returns
+            # (owned, logits, flags) and no counts
+            flags = out[1] if self.mesh is None else out[2]
+            if bool(jnp.any(flags)):
+                box["counts"] = out[2] if self.mesh is None else None
                 return None
             return out
 
         def escalate(_i):
-            self.grow()
+            self.grow(box["counts"], seeds)
             self.stats.overflow_retries += 1
-            grows["n"] += 1
+            box["grows"] += 1
 
         out = RetryPolicy(max_retries).run(
             attempt, grow=escalate, error=SamplingOverflowError,
-            describe="sampling overflow persisted after cap doubling "
+            describe="sampling overflow persisted after cap growth "
                      "while serving")
-        return (out[0] if self.mesh is None else out), grows["n"]
+        return (out[0] if self.mesh is None else out), box["grows"]
 
     # ------------------------------------------------------------------
     # AOT lowering support (launch/perf.py roofline accounting)

@@ -34,15 +34,16 @@ Overflow protocol
 The driver owns an :class:`~repro.data.gnn_loader.OverflowLedger` with
 poll lag 1 over *compute dispatches* (not driver steps). Because
 computes retire FIFO in batch order through the same record/poll
-protocol as the serial engine, the order of applied updates — each
-overflowed batch is a gated device-side no-op, replayed after the
-NEXT batch's update — is identical to the serial trace at any pipeline
-depth::
+protocol as the serial engine (``TrainEngine._settle``), the order of
+applied updates — each overflowed batch is a gated device-side no-op,
+replayed after the NEXT batch's update, or at once where it was the
+first batch under its cap schedule — is identical to the serial trace
+at any pipeline depth::
 
     serial   : u(t+1), replay(t), u(t+2), ...
     pipelined: u(t+1), replay(t), u(t+2), ...   (same, by construction)
 
-A replay doubles the cap schedule (``engine.grow()``), which
+A replay grows the cap schedule (``engine.grow``), which
 invalidates every still-queued in-flight batch: their block buffers
 were sampled at the old caps and the rebuilt compute program cannot
 consume them. :meth:`_invalidate` re-samples them with the grown
@@ -205,12 +206,11 @@ class PipelinedEngine:
         with the sampling already in flight."""
         ent = self._queue.popleft()
         params, state, m = self._compute(params, state, data, ent)
+        params, state, m = self.engine._settle(
+            self._ledger, params, state, data,
+            (ent.seeds, ent.key, ent.tag, ent.sampler), m)
         done.append((ent.tag, m))
-        due = self._ledger.record((ent.seeds, ent.key, ent.tag, ent.sampler),
-                                  self.engine._read_overflow(m))
-        if due is not None:
-            params, state, _ = self.engine._replay(params, state, data, *due)
-            self._invalidate(data)
+        self._invalidate(data)
         return params, state
 
     def _invalidate(self, data: EngineData):

@@ -13,6 +13,8 @@ The fused train step (``runtime/engine.py``) is cut into four stage
 scopes, the sampler into one scope per layer, and each frontier
 primitive gets one scope at the dispatch point every backend passes
 through (``ops/frontier.py``, ``graph/csr.py::expand_seed_edges``).
+Inside a LABOR-family layer, ``include`` names the inclusion decision
+(``core/labor.py::sample_layer``).
 """
 from __future__ import annotations
 
@@ -29,6 +31,12 @@ def layer(index: int) -> str:
     return f"layer{index}"
 
 
+# -- inside a LABOR-family sampling layer ------------------------------------
+# the expanded edges to the inclusion mask and 1/p: importance
+# iterations, variates, c_s per edge, the comparison or the exact-k
+# selection (which holds segment_select)
+INCLUDE = "include"
+
 # -- frontier primitives --------------------------------------------------
 EXPAND_SEED_EDGES = "expand_seed_edges"
 HASH_DEDUP = "hash_dedup"
@@ -42,5 +50,5 @@ ENGINE_STEP = "engine.step"        # all of TrainEngine.step
 ENGINE_DISPATCH = "engine.dispatch"  # enqueueing one fused program
 ENGINE_POLL = "engine.poll"        # reading an earlier batch's overflow flags
 ENGINE_REPLAY = "engine.replay"    # re-running an overflowed batch
-ENGINE_GROW = "engine.grow"        # doubling the caps (a recompile follows)
+ENGINE_GROW = "engine.grow"        # growing the caps (a recompile follows)
 ENGINE_FLUSH = "engine.flush"      # draining the overflow ledger

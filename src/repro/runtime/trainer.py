@@ -140,10 +140,11 @@ def make_fused_infer_step(apply_fn, sampler: Sampler, backend=None):
     """One-dispatch serving step — the engine's fused infer program.
 
     Signature: infer(params, graph, features, seeds, key) ->
-    (logits, overflow_flags). With the ``full`` registry entry the
-    logits are exact (full-neighborhood aggregation); with any other
-    entry this is sampled inference. Overflow handling is the caller's
-    usual protocol: double caps via ``sampler.doubled`` and rebuild.
+    (logits, overflow_flags, layer_counts). With the ``full`` registry
+    entry the logits are exact (full-neighborhood aggregation); with any
+    other entry this is sampled inference. Overflow handling is the
+    caller's usual protocol: grow the caps via ``sampler.grown`` from
+    ``layer_counts`` and rebuild.
     """
     return TrainEngine(sampler, apply_fn, adam.AdamConfig(), mesh=None,
                        backend=backend).infer_fn

@@ -25,9 +25,9 @@ Per-request semantics:
   only: compile events (first dispatch, every ``engine.grow``) are
   tagged and reported separately (:mod:`repro.serving.metrics`).
 * **Overflow.** A cap overflow follows the training contract:
-  ``engine.grow()`` + same-key retry, raising
-  :class:`~repro.data.gnn_loader.SamplingOverflowError` when doubling
-  stops helping. A grow invalidates the device caches (their state
+  ``engine.grow`` from the batch's measured counts + same-key retry,
+  raising :class:`~repro.data.gnn_loader.SamplingOverflowError` when
+  growth stops helping. A grow invalidates the device caches (their state
   survives shape changes, but the rebuilt program must start from a
   consistent clock) — counted in ``stats.cache_invalidations``.
 
@@ -260,6 +260,7 @@ class ServingDriver:
         self._batch_index += 1
         key = jax.random.fold_in(self._key, self._batch_index)
         self._apply_injectors()
+        box = {}
 
         def attempt(_i):
             if eng.generation != self._cache_gen:
@@ -268,15 +269,17 @@ class ServingDriver:
             compile_event = eng.generation not in self._compiled_gens
             cm = {}
             if self.feature_cache is None and self.hidden_cache is None:
-                logits, ovf = eng.infer(self.params, self.data, seeds, key)
+                logits, ovf, counts = eng.infer(self.params, self.data,
+                                                seeds, key)
                 fc2 = hc2 = None
             else:
                 fn = eng.cached_infer_fn(self.feature_cache,
                                          self.hidden_cache)
-                logits, ovf, fc2, hc2, cm = fn(
+                logits, ovf, counts, fc2, hc2, cm = fn(
                     self.params, self.data.graph, self.data.features,
                     self._fc_state, self._hc_state, seeds, key)
             if bool(jnp.any(ovf)):
+                box["counts"] = counts
                 return None
             # commit cache state only for a clean (served) dispatch
             if self.feature_cache is not None:
@@ -287,13 +290,13 @@ class ServingDriver:
             return np.asarray(logits), compile_event, cm
 
         def grow(_i):
-            eng.grow()
+            eng.grow(box["counts"], seeds)
             eng.stats.overflow_retries += 1
             self.stats.grow_events += 1
 
         return RetryPolicy(self.max_grows).run(
             attempt, grow=grow, error=SamplingOverflowError,
-            describe="sampling overflow persisted after cap doubling "
+            describe="sampling overflow persisted after cap growth "
                      "while serving")
 
     def _recover_cache_fault(self, seeds_np: np.ndarray) -> np.ndarray:
@@ -311,8 +314,8 @@ class ServingDriver:
             self._fc_state = self._hc_state = None
             self.stats.cache_fallbacks += 1
         key = jax.random.fold_in(self._key, self._batch_index)
-        logits, _ = self.engine.infer(self.params, self.data,
-                                      jnp.asarray(seeds_np), key)
+        logits, *_ = self.engine.infer(self.params, self.data,
+                                       jnp.asarray(seeds_np), key)
         return np.asarray(logits)
 
     def pump(self) -> int:

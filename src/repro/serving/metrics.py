@@ -5,7 +5,7 @@ One recorder serves both serving paths: the async driver
 baseline in ``launch/serve.py``. The important discipline — the bug
 this module exists to fix — is that COMPILE time is not latency:
 every fresh jit specialization (first dispatch, and every
-``engine.grow()`` retry, which rebuilds the program at the doubled cap
+``engine.grow()`` retry, which rebuilds the program at the grown cap
 schedule) is recorded as a tagged compile event, excluded from the
 warm p50/p99 and reported separately, instead of silently folding a
 multi-second compile into the tail percentile.

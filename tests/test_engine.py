@@ -196,7 +196,7 @@ d1 = e1.make_data_from_dataset(ds)
 dP = eP.make_data_from_dataset(ds)
 seeds = pad_seeds(jnp.asarray(np.asarray(ds.val_idx[:B], np.int32)), B)
 k = jax.random.key(9)
-logits1, ovf1 = e1.infer(base_params, d1, seeds, k)
+logits1, ovf1, _ = e1.infer(base_params, d1, seeds, k)
 owned, logitsP, ovfP = eP.infer(base_params, dP, seeds, k)
 assert not bool(jnp.any(ovf1)) and not bool(jnp.any(ovfP))
 pos = {int(v): i for i, v in enumerate(np.asarray(seeds)) if v >= 0}

@@ -76,6 +76,30 @@ def test_aggregate_fwd_parity(block):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-4)
 
 
+@pytest.mark.parametrize("f_in,f_out", [(24, 8), (24, 24), (8, 24)])
+def test_gcn_layer_aggregates_at_the_narrower_width(block, monkeypatch,
+                                                    f_in, f_out):
+    """(A h) W = A (h W): a layer that narrows projects first, so the
+    block's edge-sized message tensors take the narrower width."""
+    from repro.models import gnn as gnn_models
+    r = _rng(2)
+    h = jnp.asarray(r.normal(size=(block.next_cap, f_in)), jnp.float32)
+    p = {"w": jnp.asarray(r.normal(size=(f_in, f_out)), jnp.float32),
+         "b": jnp.zeros((f_out,), jnp.float32),
+         "wr": jnp.asarray(r.normal(size=(f_in, f_out)), jnp.float32)}
+    with jax.default_matmul_precision("highest"):
+        want = (O.aggregate(block, h, backend="xla") @ p["w"] + p["b"]
+                + h[:block.seed_cap] @ p["wr"])
+        widths = []
+        real = O.aggregate
+        monkeypatch.setattr(O, "aggregate", lambda blk, x, backend=None: (
+            widths.append(x.shape[-1]), real(blk, x, backend=backend))[1])
+        got = gnn_models.gcn_layer(p, block, h, is_last=True, backend="xla")
+    assert widths == [min(f_in, f_out)]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+
+
 def test_aggregate_vjp_vs_grad_of_ref(block):
     """The satellite contract: the Pallas custom VJP (transposed SpMM
     for dh, SDDMM for dweight) against jax.grad of aggregate_ref."""

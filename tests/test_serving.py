@@ -81,9 +81,9 @@ def _run_pair(eng, data, params, fc, hc, seeds_list, key0=11):
     out = []
     for i, seeds in enumerate(seeds_list):
         key = jax.random.fold_in(jax.random.key(key0), i)
-        base, ovf = eng.infer(params, data, seeds, key)
+        base, ovf, _ = eng.infer(params, data, seeds, key)
         assert not bool(jnp.any(ovf))
-        got, ovf2, fc_state, hc_state, _ = fn(
+        got, ovf2, _, fc_state, hc_state, _ = fn(
             params, data.graph, data.features, fc_state, hc_state, seeds,
             key)
         assert not bool(jnp.any(ovf2))
@@ -306,9 +306,9 @@ def test_hidden_cache_full_sampler_exact_any_age(ds):
     served = 0
     for i, seeds in enumerate(batches):
         key = jax.random.fold_in(jax.random.key(5), i)
-        base, _ = eng.infer(params, data, seeds, key)
-        got, _, _, hc_state, m = fn(params, data.graph, data.features,
-                                    None, hc_state, seeds, key)
+        base, *_ = eng.infer(params, data, seeds, key)
+        got, _, _, _, hc_state, m = fn(params, data.graph, data.features,
+                                       None, hc_state, seeds, key)
         valid = np.asarray(seeds) >= 0
         np.testing.assert_array_equal(np.asarray(base)[valid],
                                       np.asarray(got)[valid])
@@ -330,9 +330,9 @@ def test_hidden_cache_error_bounded_by_staleness(ds):
     max_dev, base_scale, served = 0.0, 0.0, 0
     for i, seeds in enumerate(batches):
         key = jax.random.fold_in(jax.random.key(5), i)
-        base, _ = eng.infer(params, data, seeds, key)
-        got, _, _, hc_state, m = fn(params, data.graph, data.features,
-                                    None, hc_state, seeds, key)
+        base, *_ = eng.infer(params, data, seeds, key)
+        got, _, _, _, hc_state, m = fn(params, data.graph, data.features,
+                                       None, hc_state, seeds, key)
         valid = np.asarray(seeds) >= 0
         b, g = np.asarray(base)[valid], np.asarray(got)[valid]
         max_dev = max(max_dev, float(np.abs(b - g).max()))
@@ -413,7 +413,7 @@ def test_driver_coalesces_and_answers_exactly(served, ds):
     assert all(t.status == "ok" for t in tickets)
     assert drv.stats.batches == 1  # 8 x 8 seeds packed into one B=64
     assert drv.stats.served == 8
-    ref, _ = eng.infer(params, data,
+    ref, *_ = eng.infer(params, data,
                        pad_seeds(jnp.asarray(reqs[3]), B),
                        jax.random.key(0))
     np.testing.assert_array_equal(tickets[3].logits,
@@ -496,7 +496,7 @@ def test_driver_grow_invalidates_caches(ds):
     assert all(t.status == "ok" for t in tickets)
     if drv.stats.grow_events:  # starved safety should force >= 1 grow
         assert drv.stats.cache_invalidations >= 1
-    ref, _ = eng.infer(params, data,
+    ref, *_ = eng.infer(params, data,
                        pad_seeds(jnp.asarray(tickets[-1].seeds), B),
                        jax.random.fold_in(jax.random.key(0),
                                           drv._batch_index))

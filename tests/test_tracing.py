@@ -1,6 +1,9 @@
 """The program's own measurement points: the stage and primitive scopes
 in the fused step's op paths, the per-layer counts it returns, and the
 per-layer overflow counter of the ledger poll (runtime/spans.py)."""
+import contextlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -63,8 +66,8 @@ def _scopes(path):
 
 _CASES = [(n, s) for n in SAMPLERS for s in (
     *spans.STAGES, spans.layer(0), spans.layer(1), spans.HASH_DEDUP,
-    spans.EXPAND_SEED_EDGES, spans.COMPACT_PERM, "jvp(model)",
-    "transpose(jvp(model))")] + [("ns", spans.SEGMENT_SELECT)]
+    spans.EXPAND_SEED_EDGES, spans.COMPACT_PERM, spans.INCLUDE,
+    "jvp(model)", "transpose(jvp(model))")] + [("ns", spans.SEGMENT_SELECT)]
 
 
 @pytest.mark.parametrize("sampler,scope", _CASES)
@@ -78,6 +81,50 @@ def test_scope_is_in_the_fused_step(step_paths, sampler, scope):
 def test_labor_has_no_segment_select(step_paths):
     assert not any(spans.SEGMENT_SELECT in _scopes(p)
                    for p in step_paths["labor-0"])
+
+
+def test_include_holds_the_exact_k_selection(step_paths):
+    # ns' exact-k path runs segment_select inside the inclusion scope;
+    # the expansion before it and the block built after it lie outside
+    paths = step_paths["ns"]
+    select = [p for p in paths if spans.SEGMENT_SELECT in _scopes(p)]
+    assert select and all(spans.INCLUDE in _scopes(p) for p in select)
+    for scope in (spans.EXPAND_SEED_EDGES, spans.COMPACT_PERM):
+        assert not any({scope, spans.INCLUDE} <= _scopes(p) for p in paths)
+
+
+_METADATA = re.compile(r",? metadata=\{[^}]*\}")
+# the module's source tables (files, functions, locations, stack
+# frames), which the metadata of each instruction points into
+_SOURCE_TABLE = re.compile(
+    r"^(\d+ |FileNames$|FunctionNames$|FileLocations$|StackFrames$)")
+
+
+def _without_metadata(hlo_text):
+    return _METADATA.sub("", "\n".join(
+        l for l in hlo_text.splitlines() if not _SOURCE_TABLE.match(l)))
+
+
+@pytest.mark.parametrize("sampler", SAMPLERS)
+def test_include_scope_changes_only_metadata(ds, sampler, monkeypatch):
+    """The optimised HLO of the fused step with the ``include`` scope
+    equals the one without it, metadata aside: naming the inclusion
+    decision costs nothing at run time."""
+    def hlo():
+        eng, params, data = _engine(ds, sampler)
+        state = eng.init_state(params)
+        text = eng.step_fn.lower(
+            params, state.opt, data.graph, data.features, data.labels,
+            _seeds(0), jax.random.key(1)).compile().as_text()
+        return _without_metadata(text)
+
+    scoped = hlo()
+    real = jax.named_scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: (contextlib.nullcontext()
+                                      if name == spans.INCLUDE
+                                      else real(name)))
+    assert hlo() == scoped
 
 
 @pytest.mark.parametrize("sampler", SAMPLERS)
