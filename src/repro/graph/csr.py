@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.frontier.parallel import fill_forward
 from repro.runtime import spans
 
 
@@ -134,7 +135,8 @@ def expand_seed_edges(graph: Graph, seeds: jax.Array, edge_cap: int,
       deg:       int32[S] degree of each seed (0 for padding)
       total:     int32[] total real edges (may exceed edge_cap => overflow)
 
-    Edges beyond ``edge_cap`` are dropped; callers must check
+    Edges beyond ``edge_cap`` are dropped: on overflow the buffers hold
+    the exact first ``edge_cap`` edges of the layout. Callers must check
     ``total <= edge_cap`` (the data pipeline sizes caps so overflow is
     rare and re-tries with a bigger bucket when it happens).
     """
@@ -147,32 +149,27 @@ def _expand(graph: Graph, seeds: jax.Array, edge_cap: int,
     S = seeds.shape[0]
     valid = seeds >= 0
     safe_seeds = jnp.where(valid, seeds if seed_rows is None else seed_rows, 0)
-    deg = jnp.where(valid, graph.indptr[safe_seeds + 1] - graph.indptr[safe_seeds], 0)
+    row_start = graph.indptr[safe_seeds]
+    deg = jnp.where(valid, graph.indptr[safe_seeds + 1] - row_start, 0)
     seg_start = jnp.cumsum(deg) - deg  # exclusive prefix sum
     total = jnp.sum(deg)
 
-    # Standard CSR expansion: scatter segment bumps, inclusive-scan.
-    # seed_slot[e] = (number of segment starts <= e) - 1
-    bumps = jnp.zeros((edge_cap,), jnp.int32).at[jnp.minimum(seg_start, edge_cap - 1)].add(
-        jnp.where(deg > 0, 1, 0), mode="drop"
-    )
-    seed_slot = jnp.cumsum(bumps) - 1
-    # Rows with deg==0 create no bump; but consecutive zero-degree seeds are
-    # fine because their segments are empty. seed_slot indexes only *bumped*
-    # rows; map back via sorted row ids of nonzero-degree seeds.
-    nz_rows = jnp.nonzero(deg > 0, size=S, fill_value=0)[0].astype(jnp.int32)
-    seed_slot = nz_rows[jnp.clip(seed_slot, 0, S - 1)]
+    # the fill carries each segment head's slot and row offset over its segment
+    at = jnp.where(deg > 0, seg_start, edge_cap)
+    slot_at = jnp.full((edge_cap,), -1, jnp.int32).at[at].set(
+        jnp.arange(S, dtype=jnp.int32), mode="drop")
+    delta_at = jnp.zeros((edge_cap,), jnp.int32).at[at].set(
+        row_start - seg_start, mode="drop")
+    seed_slot, delta = fill_forward(slot_at >= 0, (slot_at, delta_at))
 
     pos = jnp.arange(edge_cap, dtype=jnp.int32)
     mask = pos < jnp.minimum(total, edge_cap)
-    offset_in_seg = pos - seg_start[seed_slot]
-    row_start = graph.indptr[safe_seeds[seed_slot]]
-    src = graph.indices[jnp.where(mask, row_start + offset_in_seg, 0)]
-    src = jnp.where(mask, src, -1)
+    edge = jnp.where(mask, pos + delta, 0)
+    src = jnp.where(mask, graph.indices[edge], -1)
     seed_slot = jnp.where(mask, seed_slot, -1)
     ew = None
     if graph.weights is not None:
-        ew = jnp.where(mask, graph.weights[jnp.where(mask, row_start + offset_in_seg, 0)], 0.0)
+        ew = jnp.where(mask, graph.weights[edge], 0.0)
     return dict(
         seed_slot=seed_slot,
         src=src,

@@ -263,16 +263,18 @@ def _exclusive_cumsum(b):
 
 def fill_forward(head, x):
     """Each position takes the value of the nearest head at or before
-    it; position 0 is a head."""
+    it; position 0 is a head. ``x`` is an array or a tuple of arrays of
+    ``head``'s length, filled in the same passes."""
     # a doubling scan, log2(n) streaming shift-and-select passes:
     # jax.lax.associative_scan's strided recursion takes the TPU
     # compiler minutes from n = 2**18 on
-    n = x.shape[0]
+    n = head.shape[0]
     d = 1
     while d < n:
         # combine each position with the one d before it:
         # (fa | fb, where(fb, vb, va)), a = position - d, b = position
-        x = jnp.where(head, x, jnp.pad(x[:-d], (d, 0)))
+        x = jax.tree.map(
+            lambda v: jnp.where(head, v, jnp.pad(v[:-d], (d, 0))), x)
         head = head | jnp.pad(head[:-d], (d, 0))
         d *= 2
     return x

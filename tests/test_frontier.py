@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 from tests._hypothesis_compat import given, settings, st
+from tests._jaxpr import eqns as _eqns
 
 from repro import ops as O
 from repro.core import LayerCaps, pad_seeds, samplers
@@ -438,19 +439,6 @@ def test_ladies_candidate_path_matches_dense(ds, poisson):
 # ---------------------------------------------------------------------------
 # the acceptance criterion: no V-sized intermediates in any sample trace
 # ---------------------------------------------------------------------------
-
-def _eqns(jaxpr):
-    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for val in eqn.params.values():
-            vals = val if isinstance(val, (tuple, list)) else (val,)
-            for x in vals:
-                if hasattr(x, "jaxpr"):        # ClosedJaxpr
-                    yield from _eqns(x.jaxpr)
-                elif hasattr(x, "eqns"):       # Jaxpr
-                    yield from _eqns(x)
-
 
 def _collect_avals(jaxpr, out):
     for eqn in _eqns(jaxpr):

@@ -1,9 +1,14 @@
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests._jaxpr import eqns
+
 from repro.graph import PAPER_DATASETS, from_coo, generate, paper_dataset, reverse
-from repro.graph.csr import expand_seed_edges
+from repro.graph.csr import Graph, expand_seed_edges
 from repro.graph.partition import partition_graph, partition_features
 from repro.core.interface import pad_seeds
 
@@ -90,6 +95,93 @@ def test_expand_overflow_detected():
     seeds = pad_seeds(jnp.asarray([0]), 1)
     exp = expand_seed_edges(g, seeds, 16)
     assert int(exp["total"]) == 30  # caller compares against cap
+
+
+def _np_expand(indptr, indices, weights, seeds, rows, edge_cap):
+    """Segment-contiguous expansion, seed by seed, truncated at the cap."""
+    deg = np.array([indptr[r + 1] - indptr[r] if s >= 0 else 0
+                    for s, r in zip(seeds, rows)], np.int64)
+    slot = np.concatenate([np.full(d, i) for i, d in enumerate(deg)] + [[]])
+    edge = np.concatenate([np.arange(indptr[r], indptr[r] + d)
+                           for r, d in zip(rows, deg)] + [[]]).astype(int)
+    m = min(len(edge), edge_cap)
+    out = dict(seed_slot=np.full(edge_cap, -1), src=np.full(edge_cap, -1),
+               mask=np.arange(edge_cap) < m, seg_start=np.cumsum(deg) - deg,
+               deg=deg, total=deg.sum(), edge_weight=None)
+    out["seed_slot"][:m] = slot[:m]
+    out["src"][:m] = indices[edge[:m]]
+    if weights is not None:
+        out["edge_weight"] = np.zeros(edge_cap, np.float32)
+        out["edge_weight"][:m] = weights[edge[:m]]
+    return out
+
+
+# in-degree 0 for every vertex divisible by 3
+_V = 48
+_rng = np.random.default_rng(5)
+_DST = _rng.choice([v for v in range(_V) if v % 3], 400)
+_SRC = _rng.integers(0, _V, 400)
+
+
+@pytest.mark.parametrize("case", [
+    "interleaved_zero_degree", "leading_zero_degree", "all_padding",
+    "seed_rows", "weighted", "overflow"])
+def test_expand_seed_edges_cases(case):
+    """Every output equals a numpy expansion; on overflow the buffers
+    hold the exact first ``edge_cap`` edges of the layout."""
+    weights = (np.random.default_rng(6).uniform(0.1, 2.0, 400)
+               .astype(np.float32) if case == "weighted" else None)
+    g = from_coo(_SRC, _DST, _V, weights=weights)
+    seeds = {"interleaved_zero_degree": [1, 3, -1, 2, 6, 9, 4, -1, 5],
+             "leading_zero_degree": [0, 3, 6, 7, 2, 9, 1],
+             "all_padding": [-1] * 6,
+             "seed_rows": [5, 13, 3, 31, -1, 1, 47],
+             "weighted": [4, 0, 8, 10, 1],
+             "overflow": [2, 0, 7, 11, 3, 4, 5]}[case]
+    seeds = np.asarray(seeds, np.int32)
+    edge_cap = 36 if case == "overflow" else 256
+    rows = None
+    if case == "seed_rows":
+        # partition 1 of 2: odd global seeds, local rows v // 2
+        g = partition_graph(g, 2).part_graph(1)
+        rows = np.where(seeds >= 0, seeds // 2, 0).astype(np.int32)
+    indptr, indices = np.asarray(g.indptr), np.asarray(g.indices)
+    row_ids = np.where(seeds >= 0, seeds if rows is None else rows, 0)
+    exp = expand_seed_edges(g, jnp.asarray(seeds), edge_cap,
+                            seed_rows=None if rows is None
+                            else jnp.asarray(rows))
+    want = _np_expand(indptr, indices, None if weights is None
+                      else np.asarray(g.weights), seeds, row_ids, edge_cap)
+    if case == "overflow":
+        # the cap cuts a segment, and whole segments lie beyond it
+        assert edge_cap not in want["seg_start"]
+        assert want["seg_start"][-2] > edge_cap
+    for k, v in want.items():
+        if v is None:
+            assert exp[k] is None, k
+        else:
+            np.testing.assert_array_equal(np.asarray(exp[k]), v, err_msg=k)
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+def test_expand_trace_has_one_buffer_sized_gather(weighted):
+    """Per-seed values reach their segments by a forward fill: at a
+    layer-2-like shape the only gathers with an ``edge_cap``-sized
+    output read the edge data (indices, and weights when present)."""
+    S, edge_cap = 2**15, 2**18
+    V, E = 100_003, 3_000_017
+    g = Graph(indptr=jax.ShapeDtypeStruct((V + 1,), jnp.int32),
+              indices=jax.ShapeDtypeStruct((E,), jnp.int32),
+              weights=jax.ShapeDtypeStruct((E,), jnp.float32)
+              if weighted else None)
+    closed = jax.make_jaxpr(functools.partial(
+        expand_seed_edges, edge_cap=edge_cap))(
+        g, jax.ShapeDtypeStruct((S,), jnp.int32))
+    big = [e for e in eqns(closed.jaxpr) if e.primitive.name == "gather"
+           and any(edge_cap in getattr(v.aval, "shape", ())
+                   for v in e.outvars)]
+    assert len(big) == (2 if weighted else 1), [
+        v.aval for e in big for v in e.outvars]
 
 
 def test_generator_stats_match_spec():
